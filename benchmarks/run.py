@@ -43,6 +43,8 @@ def main() -> None:
                          "the gate expects the default budget)")
     args = ap.parse_args()
     selected = args.only.split(",") if args.only else SUITES
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     csv_rows: list = []
     t0 = time.time()

@@ -115,9 +115,15 @@ def isqrt_from_eigs(evals, evecs):
     """Pseudo-inverse square root U Λ^{-1/2} Uᵀ with eigenvalue clipping.
 
     RBF kernel blocks are PSD in exact arithmetic but near-singular when
-    landmarks cluster; eigenvalues below 1e-6·λ_max are treated as zero
-    exactly as the dense Nyström path does.
+    landmarks cluster.  Eigenvalues below the f32 noise floor of an m×m
+    eigensolve, m·ε·λ_max (numpy's ``matrix_rank`` tolerance), and never
+    less than 1e-6·λ_max, are treated as zero: Λ^{-1/2} would otherwise
+    amplify rounding noise into spurious eigenpairs of the Nyström
+    operator, which then differ between two summation orders of the same
+    sums (the jnp and fused paths, one chip and a mesh).
     """
-    good = evals > 1e-6 * jnp.max(evals)
+    m = evecs.shape[0]
+    floor = max(1e-6, m * jnp.finfo(evals.dtype).eps)
+    good = evals > floor * jnp.max(evals)
     inv = jnp.where(good, 1.0 / jnp.maximum(evals, _EPS), 0.0)
     return (evecs * jnp.sqrt(inv)[None, :]) @ evecs.T

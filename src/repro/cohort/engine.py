@@ -147,6 +147,18 @@ class CohortConfig:
             raise ValueError(
                 f"num_landmarks={m!r} must be a positive int, None, "
                 f"or \"auto\"")
+        if isinstance(m, (int, np.integer)):
+            _check_fused_landmarks(int(m), self.use_pallas)
+
+
+def _check_fused_landmarks(m: int, use_pallas: bool) -> None:
+    """Refuse a landmark count the fused kernels cannot compile for."""
+    from repro.kernels.nystrom_pallas import MAX_LANDMARKS
+    if use_pallas and m > MAX_LANDMARKS:
+        raise ValueError(
+            f"num_landmarks={m} exceeds the fused Pallas pipeline's limit "
+            f"of {MAX_LANDMARKS} landmarks (VMEM-sized kernels); use "
+            f"fewer landmarks or use_pallas=False")
 
 
 @dataclasses.dataclass
@@ -382,7 +394,19 @@ class CohortEngine:
 
     def _prepare(self, embeds: np.ndarray, fp: bytes, *, key,
                  warm_ok: bool) -> PreparedSolve:
-        """The full solve, staged: reads engine state, never writes it."""
+        """The full solve, staged: reads engine state, never writes it.
+
+        Every matmul of the solve runs at full f32 precision.  A TPU's
+        default f32 matmul is one bf16 pass, which blurs the
+        ‖x‖² + ‖z‖² − 2·x·zᵀ affinity and the ill-conditioned W⁻¹ᐟ²
+        products enough to scramble the partition (purity 0.35 on a
+        planted 8-cluster table of 10⁶ clients).
+        """
+        with jax.default_matmul_precision("highest"):
+            return self._prepare_f32(embeds, fp, key=key, warm_ok=warm_ok)
+
+    def _prepare_f32(self, embeds: np.ndarray, fp: bytes, *, key,
+                     warm_ok: bool) -> PreparedSolve:
         cfg = self.config
         st = self.state
         t0 = time.perf_counter()
@@ -489,6 +513,7 @@ class CohortEngine:
         m = min(int(m), n)
         if m < k:
             raise ValueError(f"num_landmarks={m} must be >= k={k}")
+        _check_fused_landmarks(m, self.config.use_pallas)
         return m
 
     def _update_auto_m(self, n: int, k: int, drift: float,
@@ -513,6 +538,9 @@ class CohortEngine:
         self._gap_hist.append(gap)
         base = _spectral.default_num_landmarks(n, k)
         cap = min(n, _AUTO_M_MAX_FACTOR * base)
+        if self.config.use_pallas:
+            from repro.kernels.nystrom_pallas import MAX_LANDMARKS
+            cap = min(cap, MAX_LANDMARKS)
         m = self._auto_m or base
         if gap < _GAP_WEAK:
             m = min(cap, 2 * m)

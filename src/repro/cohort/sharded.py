@@ -21,7 +21,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.cohort.nystrom import (_nystrom_core, _nystrom_core_fused,
@@ -43,8 +42,8 @@ def _build_sharded_fn(mesh, k: int, mm_solver: str, warm: bool,
         if fused:
             # streaming pipeline: each shard's (N/D, m) C panel lives
             # only tile-by-tile in VMEM; the same two psums (col, SᵀS)
-            # fire inside the fused core — the Gram kernel's last-step
-            # W⁻¹ᐟ² rotation is linear, so per-shard rotated Grams sum
+            # fire inside the fused core — the Gram pass's W⁻¹ᐟ²
+            # rotation is linear, so per-shard rotated Grams sum
             # to the rotated global Gram.
             return _nystrom_core_fused(
                 x_s, z, gamma, w_isqrt, k, mask=mask_s, axis_name=axis,
@@ -58,14 +57,14 @@ def _build_sharded_fn(mesh, k: int, mm_solver: str, warm: bool,
             mm_iters=iters, mm_q0=mm_q0 if warm else None,
             key=None, block_rows=block_rows)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis, None), P(axis), P(), P(), P(), P()),
         out_specs=(P(axis, None), P(), P()),
-        # pallas_call has no replication rule yet; the replicated (P())
-        # outputs are psum-derived either way, so the check adds nothing
-        # on the kernel path
-        check_rep=not (use_pallas or fused))
+        # pallas_call has no varying-manual-axes rule; the replicated
+        # (P()) outputs are psum-derived either way, so the check adds
+        # nothing on the kernel path
+        check_vma=not (use_pallas or fused))
     return jax.jit(fn)
 
 

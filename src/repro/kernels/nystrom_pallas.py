@@ -13,9 +13,10 @@ row panels, each tile living and dying in VMEM:
 2. ``nystrom_gram_pallas``     — recompute the tile, apply the
    ``rsqrt(d̂)`` degree scaling in-register (``d̂ = C·u`` folds the
    m-sized ``u = W⁻¹ᐟ²(W⁻¹ᐟ² col)`` the caller derives from pass 1),
-   accumulate the (m, m) ``SᵀS`` Gram across the grid, and rotate by
-   ``W⁻¹ᐟ²`` on the LAST grid step only — rotation is linear, so the
-   per-shard ``psum`` composition of ``cohort/sharded.py`` is unchanged:
+   and accumulate the (m, m) ``SᵀS`` Gram across the grid, one (m, bn)
+   column block per outer grid step; the wrapper then rotates by
+   ``W⁻¹ᐟ²`` — rotation is linear, so the per-shard ``psum``
+   composition of ``cohort/sharded.py`` is unchanged:
    ``psum(W⁻¹ᐟ² SᵀS_s W⁻¹ᐟ²) = W⁻¹ᐟ² (Σ_s SᵀS_s) W⁻¹ᐟ²``.
 3. ``nystrom_extension_pallas`` — recompute the tile a third time and
    emit the row-normalized embedding ``V = S · proj`` directly, where
@@ -39,6 +40,12 @@ A zero/one row ``mask`` (n,) input covers both the wrapper's own row
 padding and the global padding of the ``shard_map`` path: a masked row
 contributes a zero row of C, hence nothing to ``col`` or ``SᵀS``, and a
 zero (later sliced-off) row of V.
+
+VMEM sizing: every (rows, m) tile is capped at ``_TILE_BYTES`` by
+shrinking the row panel as m grows (``_row_block``), and the Gram's
+(m, m) accumulator is split into (m, bn) column blocks of at most
+``_GRAM_BLOCK_BYTES``, so the passes compile for a v5e chip at every
+m up to ``MAX_LANDMARKS``; the engine refuses larger m before tracing.
 """
 
 from __future__ import annotations
@@ -48,11 +55,29 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _EPS = 1e-12      # degree / row-norm floor — matches cohort/nystrom.py
 _QEPS = 1e-8      # int8 scale floor for all-zero rows
 
 AFFINITY_DTYPES = ("f32", "bf16", "int8")
+
+#: largest landmark count the fused passes and the panel matmul compile
+#: for on a v5e chip (tests/test_tpu_compile.py holds them to it)
+MAX_LANDMARKS = 4096
+_TILE_BYTES = 2 << 20          # one f32 (rows, m) tile in VMEM
+_GRAM_BLOCK_BYTES = 8 << 20    # one f32 (m, bn) Gram column block
+# scoped VMEM for these kernels: a handful of tiles plus double-buffered
+# blocks, well inside a v5e core's 128 MiB
+_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=64 << 20)
+
+
+def _dot(a, b, dims):
+    """f32 tile matmul at full f32 precision (a TPU's default f32 matmul
+    is one bf16 pass), accumulated in f32."""
+    return jax.lax.dot_general(a, b, dims,
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
 
 
 def _quantize_rows(a):
@@ -76,20 +101,21 @@ def _affinity_tile(x, z, gamma, affinity_dtype: str):
     z = z.astype(jnp.float32)
     if affinity_dtype == "f32":
         xc, zc = x, z
-        xy = jax.lax.dot_general(x, z, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
+        xy = _dot(x, z, (((1,), (1,)), ((), ())))
     elif affinity_dtype == "bf16":
         xb = x.astype(jnp.bfloat16)
         zb = z.astype(jnp.bfloat16)
         xc = xb.astype(jnp.float32)
         zc = zb.astype(jnp.float32)
         xy = jax.lax.dot_general(xb, zb, (((1,), (1,)), ((), ())),
+                                 precision=jax.lax.Precision.DEFAULT,
                                  preferred_element_type=jnp.float32)
     elif affinity_dtype == "int8":
         qx, sx = _quantize_rows(x)                 # (bm, d), (bm, 1)
         qz, sz = _quantize_rows(z)                 # (bn, d), (bn, 1)
         acc = jax.lax.dot_general(qx.astype(jnp.int8), qz.astype(jnp.int8),
                                   (((1,), (1,)), ((), ())),
+                                  precision=jax.lax.Precision.DEFAULT,
                                   preferred_element_type=jnp.int32)
         xy = acc.astype(jnp.float32) * (sx * sz.T)
         xc = qx * sx
@@ -105,8 +131,7 @@ def _affinity_tile(x, z, gamma, affinity_dtype: str):
 
 def _s_tile(c, u):
     """Degree-normalized tile S = C·rsqrt(max(C·u, eps)) in-register."""
-    d_hat = jax.lax.dot_general(c, u, (((1,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32)  # (bm,1)
+    d_hat = _dot(c, u, (((1,), (0,)), ((), ())))                 # (bm, 1)
     return c * jax.lax.rsqrt(jnp.maximum(d_hat, _EPS))
 
 
@@ -125,30 +150,24 @@ def _colsum_kernel(x_ref, z_ref, g_ref, mask_ref, o_ref, *, affinity_dtype):
 
 
 # --------------------------------------------------------------------------
-# pass 2: fused affinity + degree scaling + SᵀS Gram (+ last-step rotation)
+# pass 2: fused affinity + degree scaling + SᵀS Gram, in column blocks
 # --------------------------------------------------------------------------
 
-def _gram_kernel(x_ref, z_ref, g_ref, u_ref, wis_ref, mask_ref, o_ref, *,
+def _gram_kernel(x_ref, z_ref, zj_ref, g_ref, u_ref, mask_ref, o_ref, *,
                  affinity_dtype):
-    i = pl.program_id(0)
-    c = _affinity_tile(x_ref[...], z_ref[...], g_ref[0, 0], affinity_dtype)
-    c = c * mask_ref[...]
-    s = _s_tile(c, u_ref[...])
+    # grid (column block j, row panel i): the full-width tile gives the
+    # degree scaling and the Gram's rows, the (bm, bn) tile of landmark
+    # block j its columns
+    i = pl.program_id(1)
+    x, gamma, mask = x_ref[...], g_ref[0, 0], mask_ref[...]
+    c = _affinity_tile(x, z_ref[...], gamma, affinity_dtype) * mask
+    d_hat = _dot(c, u_ref[...], (((1,), (0,)), ((), ())))
+    scale = jax.lax.rsqrt(jnp.maximum(d_hat, _EPS))             # (bm, 1)
+    s_j = _affinity_tile(x, zj_ref[...], gamma, affinity_dtype) * mask
     @pl.when(i == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
-    o_ref[...] += jax.lax.dot_general(s, s, (((0,), (0,)), ((), ())),
-                                      preferred_element_type=jnp.float32)
-    # W⁻¹ᐟ² rotation once, on the final accumulated Gram — linear, so the
-    # sharded psum over per-shard outputs still composes (see module doc)
-    @pl.when(i == pl.num_programs(0) - 1)
-    def _rotate():
-        wis = wis_ref[...]
-        o_ref[...] = jax.lax.dot_general(
-            jax.lax.dot_general(wis, o_ref[...], (((1,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32),
-            wis, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    o_ref[...] += _dot(c * scale, s_j * scale, (((0,), (0,)), ((), ())))
 
 
 # --------------------------------------------------------------------------
@@ -160,8 +179,7 @@ def _extension_kernel(x_ref, z_ref, g_ref, u_ref, proj_ref, mask_ref, o_ref,
     c = _affinity_tile(x_ref[...], z_ref[...], g_ref[0, 0], affinity_dtype)
     c = c * mask_ref[...]
     s = _s_tile(c, u_ref[...])
-    v = jax.lax.dot_general(s, proj_ref[...], (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # (bm, k)
+    v = _dot(s, proj_ref[...], (((1,), (0,)), ((), ())))         # (bm, k)
     norm = jnp.sqrt(jnp.sum(v * v, axis=-1, keepdims=True))
     o_ref[...] = v / jnp.maximum(norm, _EPS)
 
@@ -171,9 +189,7 @@ def _extension_kernel(x_ref, z_ref, g_ref, u_ref, proj_ref, mask_ref, o_ref,
 # --------------------------------------------------------------------------
 
 def _panel_matmul_kernel(w_ref, q_ref, o_ref):
-    o_ref[...] = jax.lax.dot_general(
-        w_ref[...], q_ref[...], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    o_ref[...] = _dot(w_ref[...], q_ref[...], (((1,), (0,)), ((), ())))
 
 
 def _quant_cross_kernel(x_ref, y_ref, g_ref, o_ref, *, affinity_dtype):
@@ -185,9 +201,22 @@ def _round_up(v: int, mult: int) -> int:
     return -(-v // mult) * mult
 
 
-def _row_block(n: int, block_m: int) -> int:
-    """Effective row-panel height: never pad small n up to a huge panel."""
+def _row_block(n: int, block_m: int, width: int = 0) -> int:
+    """Effective row-panel height for a (rows, width) f32 tile.
+
+    Never pads small n up to a huge panel, and shrinks the panel as the
+    tile widens so it stays within ``_TILE_BYTES`` of VMEM.
+    """
+    if width:
+        block_m = min(block_m, max(8, _TILE_BYTES // (4 * width) // 8 * 8))
     return min(block_m, _round_up(max(n, 1), 8))
+
+
+def _gram_col_block(m: int) -> int:
+    """Column-block width of the (m, m) Gram accumulator."""
+    if 4 * m * m <= _GRAM_BLOCK_BYTES:
+        return m
+    return max(128, _GRAM_BLOCK_BYTES // (4 * m) // 128 * 128)
 
 
 def _pad_rows_mask(x, mask, bm):
@@ -216,7 +245,7 @@ def nystrom_colsum_pallas(x, z, gamma, mask=None, *,
     """
     n = x.shape[0]
     m = z.shape[0]
-    bm = _row_block(n, block_m)
+    bm = _row_block(n, block_m, m)
     xp, maskp = _pad_rows_mask(x, mask, bm)
     gamma_arr = jnp.asarray(gamma, jnp.float32).reshape(1, 1)
     kern = functools.partial(_colsum_kernel, affinity_dtype=affinity_dtype)
@@ -230,6 +259,7 @@ def nystrom_colsum_pallas(x, z, gamma, mask=None, *,
                   pl.BlockSpec((bm, 1), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((1, m), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, m), jnp.float32),
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(xp, z, gamma_arr, maskp)
     return out[0]
@@ -244,30 +274,40 @@ def nystrom_gram_pallas(x, z, gamma, u, w_isqrt, mask=None, *,
 
     ``u`` (m,) is ``W⁻¹ᐟ²(W⁻¹ᐟ² col)`` from pass 1 (globally psummed on
     the sharded path); ``w_isqrt`` (m, m).  Returns the rotated (m, m)
-    Gram — symmetrize and eigensolve on the host side.
+    Gram — symmetrize and eigensolve on the host side.  The kernel
+    accumulates the unrotated SᵀS in (m, bn) column blocks; landmarks
+    padded up to a block multiple get ``u = 0`` and are sliced off.
     """
     n = x.shape[0]
     m = z.shape[0]
-    bm = _row_block(n, block_m)
+    bm = _row_block(n, block_m, m)
+    bn = _gram_col_block(m)
+    m_pad = _round_up(m, bn)
     xp, maskp = _pad_rows_mask(x, mask, bm)
     gamma_arr = jnp.asarray(gamma, jnp.float32).reshape(1, 1)
-    u2 = jnp.asarray(u, jnp.float32).reshape(m, 1)
+    zp = jnp.pad(z, ((0, m_pad - m), (0, 0)))
+    u2 = jnp.pad(jnp.asarray(u, jnp.float32), (0, m_pad - m)).reshape(
+        m_pad, 1)
     kern = functools.partial(_gram_kernel, affinity_dtype=affinity_dtype)
     d = x.shape[1]
-    out = pl.pallas_call(
+    sts = pl.pallas_call(
         kern,
-        grid=(xp.shape[0] // bm,),
-        in_specs=[pl.BlockSpec((bm, d), lambda i: (i, 0)),
-                  pl.BlockSpec((m, d), lambda i: (0, 0)),
-                  pl.BlockSpec((1, 1), lambda i: (0, 0)),
-                  pl.BlockSpec((m, 1), lambda i: (0, 0)),
-                  pl.BlockSpec((m, m), lambda i: (0, 0)),
-                  pl.BlockSpec((bm, 1), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((m, m), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((m, m), jnp.float32),
+        grid=(m_pad // bn, xp.shape[0] // bm),
+        in_specs=[pl.BlockSpec((bm, d), lambda j, i: (i, 0)),
+                  pl.BlockSpec((m_pad, d), lambda j, i: (0, 0)),
+                  pl.BlockSpec((bn, d), lambda j, i: (j, 0)),
+                  pl.BlockSpec((1, 1), lambda j, i: (0, 0)),
+                  pl.BlockSpec((m_pad, 1), lambda j, i: (0, 0)),
+                  pl.BlockSpec((bm, 1), lambda j, i: (i, 0))],
+        out_specs=pl.BlockSpec((m_pad, bn), lambda j, i: (0, j)),
+        out_shape=jax.ShapeDtypeStruct((m_pad, m_pad), jnp.float32),
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
-    )(xp, z, gamma_arr, u2, jnp.asarray(w_isqrt, jnp.float32), maskp)
-    return out
+    )(xp, zp, zp, gamma_arr, u2, maskp)[:m, :m]
+    # W⁻¹ᐟ² rotation on the accumulated Gram — linear, so the sharded
+    # psum over per-shard outputs still composes (see module doc)
+    w_isqrt = jnp.asarray(w_isqrt, jnp.float32)
+    return w_isqrt @ sts @ w_isqrt
 
 
 @functools.partial(jax.jit, static_argnames=("affinity_dtype", "block_m",
@@ -284,7 +324,7 @@ def nystrom_extension_pallas(x, z, gamma, u, proj, mask=None, *,
     n = x.shape[0]
     m = z.shape[0]
     k = proj.shape[1]
-    bm = _row_block(n, block_m)
+    bm = _row_block(n, block_m, m)
     xp, maskp = _pad_rows_mask(x, mask, bm)
     gamma_arr = jnp.asarray(gamma, jnp.float32).reshape(1, 1)
     u2 = jnp.asarray(u, jnp.float32).reshape(m, 1)
@@ -302,6 +342,7 @@ def nystrom_extension_pallas(x, z, gamma, u, proj, mask=None, *,
                   pl.BlockSpec((bm, 1), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((bm, k), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((xp.shape[0], k), jnp.float32),
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(xp, z, gamma_arr, u2, jnp.asarray(proj, jnp.float32), maskp)
     return out[:n]
@@ -315,11 +356,12 @@ def panel_matmul_pallas(w, q, *, block_rows: int = 2048,
     The Pallas twin of ``cohort/eigensolver.py::_blocked_matmul``: the
     subspace sweep's W·Q product evaluated one (block_rows, p) panel at a
     time so peak residency stays O(block_rows·p), without round-tripping
-    each panel through a separate XLA dispatch.
+    each panel through a separate XLA dispatch.  Panels of wide ``w``
+    are shortened to fit VMEM (``_row_block``).
     """
     m, p = w.shape
     r = q.shape[1]
-    bl = _row_block(m, block_rows)
+    bl = _row_block(m, block_rows, p)
     pad = (-m) % bl
     wp = jnp.pad(w.astype(jnp.float32), ((0, pad), (0, 0))) if pad \
         else w.astype(jnp.float32)
@@ -330,6 +372,7 @@ def panel_matmul_pallas(w, q, *, block_rows: int = 2048,
                   pl.BlockSpec((p, r), lambda i: (0, 0))],
         out_specs=pl.BlockSpec((bl, r), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((wp.shape[0], r), jnp.float32),
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(wp, q.astype(jnp.float32))
     return out[:m]
@@ -351,7 +394,7 @@ def quantized_cross_affinity_pallas(x, y, gamma, *,
     """
     n = x.shape[0]
     m = y.shape[0]
-    bm = _row_block(n, block_m)
+    bm = _row_block(n, block_m, m)
     xp, _ = _pad_rows_mask(x, None, bm)
     gamma_arr = jnp.asarray(gamma, jnp.float32).reshape(1, 1)
     kern = functools.partial(_quant_cross_kernel,
@@ -365,6 +408,7 @@ def quantized_cross_affinity_pallas(x, y, gamma, *,
                   pl.BlockSpec((1, 1), lambda i: (0, 0))],
         out_specs=pl.BlockSpec((bm, m), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((xp.shape[0], m), jnp.float32),
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(xp, y, gamma_arr)
     return out[:n]

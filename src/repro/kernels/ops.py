@@ -1,8 +1,8 @@
 """Jitted public wrappers for the Pallas kernels.
 
-Backend selection: on TPU the compiled kernels run natively; elsewhere
-(this CPU container) ``interpret=True`` executes the kernel bodies in
-Python for correctness validation.  ``set_use_pallas`` flips the model
+Backend selection: on TPU the compiled kernels run natively; on CPU
+``interpret=True`` executes the kernel bodies in Python for correctness
+validation.  Any other backend is an error, never a silent interpreter.  ``set_use_pallas`` flips the model
 substrate between the pure-jnp paths and the kernels globally; the
 toggle is lock-guarded (serving threads flip it around probe solves) and
 ``use_pallas_scoped`` restores the previous value on exit.
@@ -74,7 +74,12 @@ def use_pallas_scoped(flag: bool = True):
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"Pallas kernels run compiled on TPU or interpreted on CPU; "
+            f"the default backend is {backend!r}")
+    return backend == "cpu"
 
 
 def pairwise_sq_dists(x, y, **kw):

@@ -453,6 +453,8 @@ class CohortServer:
         deduper:      share a :class:`repro.streaming.SolveDeduper` so
             identical-fingerprint tenants ride one solve; None disables
             dedupe for this server.
+        mesh:         cohort mesh for the engine's sharded path; None
+            spans every visible device (``make_cohort_mesh``).
     """
 
     POLICIES = ("stratified", "dqn")
@@ -462,7 +464,7 @@ class CohortServer:
                  target_accuracy: float = 0.85,
                  dqn_overrides: Optional[dict] = None,
                  state_features: str = "rich",
-                 streaming=None, solver=None, deduper=None):
+                 streaming=None, solver=None, deduper=None, mesh=None):
         from repro.cohort import CohortConfig, CohortEngine
         from repro.fed.metrics import serving_state_dim
 
@@ -470,7 +472,7 @@ class CohortServer:
             raise ValueError(f"unknown policy {policy!r}; "
                              f"expected one of {self.POLICIES}")
         self.config = config or CohortConfig()
-        self.engine = CohortEngine(self.config, seed=seed)
+        self.engine = CohortEngine(self.config, seed=seed, mesh=mesh)
         self.rng = np.random.default_rng(seed)
         self.policy_name = policy
         self.target_accuracy = target_accuracy
@@ -1029,6 +1031,20 @@ class CohortServer:
         }
 
 
+def planted_table(n: int, k: int, d: int = 8, seed: int = 0):
+    """Synthetic (n, d) client table around k planted centers.
+
+    Returns ``(embeds (n, d) f32, labels (n,))``: centers ~ N(0, 6²),
+    unit-variance scatter — the population the cohort demo and the chip
+    smoke cluster, made from ``seed``.
+    """
+    rng = np.random.default_rng(seed)
+    centers = rng.normal(size=(k, d)).astype(np.float32) * 6
+    labels = rng.integers(0, k, n)
+    embeds = centers[labels] + rng.normal(size=(n, d)).astype(np.float32)
+    return embeds, labels
+
+
 def _cohort_main(args) -> None:
     """Cohort-service demo loop: N synthetic clients, drifting embeddings.
 
@@ -1041,12 +1057,10 @@ def _cohort_main(args) -> None:
     from repro.cohort import CohortConfig
     from repro.streaming import StreamingSpec
 
-    rng = np.random.default_rng(args.seed)
     d = 8
-    centers = rng.normal(size=(args.num_clusters, d)).astype(np.float32) * 6
-    assign_true = rng.integers(0, args.num_clusters, args.cohort)
-    embeds = (centers[assign_true]
-              + rng.normal(size=(args.cohort, d)).astype(np.float32))
+    embeds, assign_true = planted_table(args.cohort, args.num_clusters, d,
+                                        args.seed)
+    rng = np.random.default_rng(args.seed + 1)
     num_landmarks = args.num_landmarks
     if num_landmarks not in (None, "auto"):
         num_landmarks = int(num_landmarks)
@@ -1075,8 +1089,11 @@ def _cohort_main(args) -> None:
               f"clients/s, reward {reward:+.3f})")
     server.close()
     import json
-    print("server stats:", json.dumps(server.stats(), indent=2,
-                                      default=float))
+    stats = server.stats()
+    print("server stats:", json.dumps(stats, indent=2, default=float))
+    solver = stats["streaming"].get("solver")
+    if solver and solver["errors"]:
+        raise SystemExit(f"{solver['errors']} background solve(s) failed")
 
 
 def main() -> None:
@@ -1129,6 +1146,8 @@ def main() -> None:
                          "versions behind (default: never)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.cohort:
         if args.tenants:
             from repro.launch.frontend import run_demo

@@ -125,6 +125,8 @@ def main() -> None:
     ap.add_argument("--target-accuracy", type=float, default=0.85)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     (train_fl if args.fl else train_lm)(args)
 
 

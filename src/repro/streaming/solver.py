@@ -23,6 +23,7 @@ any other lock.
 from __future__ import annotations
 
 import dataclasses
+import logging
 import os
 import threading
 import time
@@ -30,6 +31,8 @@ from collections import OrderedDict
 from typing import Callable, Optional
 
 __all__ = ["BackgroundSolver", "StreamingSpec"]
+
+_log = logging.getLogger(__name__)
 
 #: nice value for worker threads; see :func:`_deprioritize`.
 _WORKER_NICENESS = 10
@@ -158,6 +161,9 @@ class BackgroundSolver:
                     self.stats["runs"] += 1
                 fn()
             except Exception:
+                # a worker must outlive one bad task: log the traceback
+                # and count it, so callers can refuse to report success
+                _log.exception("background task for %r failed", key)
                 with self._queue_lock:
                     self.stats["errors"] += 1
             finally:

@@ -37,6 +37,22 @@ def purity(assign, labels):
                for c in np.unique(assign)) / len(labels)
 
 
+def test_engine_solves_at_highest_matmul_precision(monkeypatch):
+    """A TPU's default f32 matmul is one bf16 pass; the whole solve
+    (landmarks, Nyström, k-means) must trace at full precision."""
+    seen = []
+    solve = CohortEngine._prepare_f32
+
+    def spy(self, *a, **kw):
+        seen.append(jax.config.jax_default_matmul_precision)
+        return solve(self, *a, **kw)
+
+    monkeypatch.setattr(CohortEngine, "_prepare_f32", spy)
+    x, _ = blobs(n=300)
+    CohortEngine(CohortConfig(num_clusters=4), seed=0).select(x)
+    assert seen == ["highest"]
+
+
 def same_partition(a, b):
     """Label-permutation-invariant equality of two clusterings."""
     pa = a[:, None] == a[None, :]

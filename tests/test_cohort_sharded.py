@@ -49,6 +49,19 @@ def test_ci_forced_device_count_wiring():
         assert len(jax.devices()) == 8
 
 
+def test_cohort_mesh_device_count_and_auto_axes():
+    """``num_devices`` takes the first devices (1 pins one chip on a
+    multi-chip host); asking for more than exist is refused; the axis
+    is Auto so k-means' contraction over sharded rows partitions."""
+    from jax.sharding import AxisType
+    one = make_cohort_mesh(1)
+    assert one.devices.size == 1 and one.devices[0] == jax.devices()[0]
+    assert make_cohort_mesh().devices.size == len(jax.devices())
+    assert one.axis_types == (AxisType.Auto,)
+    with pytest.raises(ValueError, match="requested"):
+        make_cohort_mesh(len(jax.devices()) + 1)
+
+
 def test_sharded_allclose_to_single_device_nystrom():
     """Acceptance: identical landmarks + bandwidth -> the sharded path
     reproduces the single-device Nyström embedding to f32 reduction

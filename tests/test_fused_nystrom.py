@@ -107,6 +107,24 @@ def test_fused_passes_match_oracles(affinity_dtype, block_m):
         rtol=2e-5, atol=2e-4)
 
 
+def test_gram_column_blocks_match_oracle():
+    """Past the VMEM-sized column block the Gram accumulates in several
+    (m, bn) blocks over zero-padded landmarks; the padding must not leak
+    into the degree scaling or the sliced-off result."""
+    from repro.kernels import nystrom_pallas
+    m = 1500
+    assert nystrom_pallas._gram_col_block(m) < m
+    x, _, gamma, mask, *_ = _fixture(n=40, d=8)
+    rng = np.random.default_rng(5)
+    z = jnp.asarray(rng.normal(size=(m, 8)), jnp.float32)
+    u = jnp.asarray(rng.random(m) + 0.1, jnp.float32)
+    wis = jnp.asarray(rng.normal(size=(m, m)) / m, jnp.float32)
+    np.testing.assert_allclose(
+        ops.nystrom_gram(x, z, gamma, u, wis, mask),
+        ref.nystrom_gram_ref(x, z, gamma, u, wis, mask),
+        rtol=2e-4, atol=2e-5)
+
+
 def test_unmasked_equals_ones_mask():
     x, z, gamma, _, u, wis, proj = _fixture()
     ones = jnp.ones((x.shape[0],), jnp.float32)
@@ -238,6 +256,25 @@ def test_quantized_engine_purity_floor_on_skewed_fixture(method,
                        seed=0)
     res = eng.select(x)
     assert purity(res.assign, labels) >= 0.95
+
+
+def test_engine_refuses_landmarks_past_kernel_limit():
+    """A landmark count the fused kernels cannot compile for is refused
+    with the limit named — by the config when pinned, by the engine
+    before tracing when the default max(8k, 64) gets there — and never
+    rerouted to the jnp path."""
+    from repro.kernels.nystrom_pallas import MAX_LANDMARKS
+    too_many = MAX_LANDMARKS + 1
+    with pytest.raises(ValueError, match=str(MAX_LANDMARKS)):
+        CohortConfig(num_landmarks=too_many, use_pallas=True)
+    CohortConfig(num_landmarks=too_many)            # jnp path: no limit
+    k = MAX_LANDMARKS // 8 + 1                       # default m = 8k
+    eng = CohortEngine(CohortConfig(num_clusters=k, use_pallas=True,
+                                    method="nystrom"), seed=0)
+    x = np.zeros((8 * k + 1, 2), np.float32)
+    with pytest.raises(ValueError, match=str(MAX_LANDMARKS)):
+        eng.select(x)
+    assert eng.stats["solves"] == 0
 
 
 def test_engine_affinity_dtype_validation():

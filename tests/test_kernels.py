@@ -123,3 +123,15 @@ def test_flash_attention_ref_oracle_matches_attention_ref():
     got = ref.flash_attention_ref(q, k, v, causal=True, window=8)
     want = ref.attention_ref(q, k, v, causal=True, window=8)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want))
+
+
+def test_interpret_mode_only_on_cpu(monkeypatch):
+    """CPU interprets, TPU compiles, and any other backend is refused
+    rather than silently interpreted."""
+    from repro.kernels import ops as kops
+    for backend, interpret in (("cpu", True), ("tpu", False)):
+        monkeypatch.setattr(jax, "default_backend", lambda b=backend: b)
+        assert kops._interpret() is interpret
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="'gpu'"):
+        kops._interpret()

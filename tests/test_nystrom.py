@@ -87,13 +87,15 @@ def test_subspace_clustering_separates_blobs():
 
 
 def test_nystrom_pallas_path_agrees():
+    """The two paths may differ by a rotation inside the degenerate
+    leading eigenspace, so compare the rotation-free projector Y·Yᵀ."""
     x, _ = blobs(n=96)
     y_jnp, _ = nystrom_spectral_embedding(KEY, jnp.asarray(x), 2, 24,
                                           gamma=0.5, use_pallas=False)
     y_pal, _ = nystrom_spectral_embedding(KEY, jnp.asarray(x), 2, 24,
                                           gamma=0.5, use_pallas=True)
-    np.testing.assert_allclose(np.asarray(y_jnp), np.asarray(y_pal),
-                               atol=1e-3)
+    y_jnp, y_pal = np.asarray(y_jnp), np.asarray(y_pal)
+    np.testing.assert_allclose(y_pal @ y_pal.T, y_jnp @ y_jnp.T, atol=1e-3)
 
 
 def test_nystrom_all_landmarks_degenerates_gracefully():
@@ -149,3 +151,28 @@ def test_dqre_sc_select_100k_clients():
     sel = pol.select(RoundState(0, embeds, np.zeros(d, np.float32), 0.1))
     assert len(sel) == 64
     assert len(set(sel.tolist())) == 64
+
+
+@pytest.mark.parametrize("n", [7, 16, 50])
+def test_blocked_gram_equals_full_product(n):
+    """SᵀS summed over row slices (ragged tail, fewer rows than one
+    slice, an exact multiple) equals the one-shot product."""
+    from repro.cohort.nystrom import _gram
+    s = jnp.asarray(np.random.default_rng(n).normal(size=(n, 5)),
+                    jnp.float32)
+    np.testing.assert_allclose(_gram(s, block=16), s.T @ s,
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_isqrt_drops_eigenvalues_below_the_f32_noise_floor():
+    """W's eigenvalues under m·ε·λ_max are rounding noise and get no
+    Λ^{-1/2} weight; those above keep it."""
+    from repro.cohort.eigensolver import isqrt_from_eigs
+    m = 64
+    floor = m * np.finfo(np.float32).eps                # ~7.6e-6
+    evals = jnp.asarray([1.0, 2 * floor, 0.5 * floor] + [1.0] * (m - 3),
+                        jnp.float32)
+    got = np.diag(np.asarray(isqrt_from_eigs(evals, jnp.eye(m))))
+    np.testing.assert_allclose(got[:2], 1 / np.sqrt([1.0, 2 * floor]),
+                               rtol=1e-5)
+    assert got[2] == 0.0
