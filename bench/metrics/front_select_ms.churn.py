@@ -1,0 +1,4 @@
+"""``front_select_ms`` in a cell whose select latency is not an end-to-end metric
+(it spreads too widely there): the same reading, moving freshness."""
+
+from bench.metrics.front_select_ms import read  # noqa: F401
