@@ -32,9 +32,10 @@ from typing import Dict, Iterable, List, Optional, Tuple
 #: family registers into (see docs/ANALYSIS.md for the full catalog).
 RULES: Dict[str, str] = {
     "jax-host-time": (
-        "wall-clock call (time.time/perf_counter/...) inside code traced "
-        "by jax.jit/shard_map/pallas_call — the value freezes at trace "
-        "time"),
+        "wall-clock call (time.time/perf_counter/...) or host span "
+        "(repro.obs.span, jax.profiler.TraceAnnotation) inside code "
+        "traced by jax.jit/shard_map/pallas_call — the value freezes, "
+        "the span fires once, at trace time"),
     "jax-host-random": (
         "np.random / stdlib random inside traced code — untracked "
         "host-side entropy breaks reproducibility and freezes at trace "

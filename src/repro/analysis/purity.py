@@ -4,7 +4,9 @@ Applies to every function reachable from a trace entry point
 (``jax.jit`` / ``shard_map`` / ``pl.pallas_call`` — see
 ``repro.analysis.callgraph``):
 
-* ``jax-host-time``   — ``time.time()`` and friends freeze at trace time.
+* ``jax-host-time``   — ``time.time()`` and friends freeze at trace time;
+  so does a host span (``repro.obs.span``, ``jax.profiler
+  .TraceAnnotation``), which fires once, while tracing.
 * ``jax-host-random`` — ``np.random`` / stdlib ``random`` is invisible to
   jax's functional PRNG: the draw happens once, at trace time.
 * ``jax-host-sync``   — ``.item()`` / ``float(x)`` / ``np.asarray(x)`` on
@@ -47,6 +49,27 @@ _TIME_CALLS = {"time.time", "time.perf_counter", "time.monotonic",
                "datetime.datetime.now", "datetime.datetime.utcnow"}
 
 
+def _span_heads(mi) -> Set[str]:
+    """Dotted names that open a host profiler span in this module."""
+    heads = {"jax.profiler.TraceAnnotation", "repro.obs.span"}
+    for alias, mod in mi.import_modules.items():
+        if mod == "jax":
+            heads.add(f"{alias}.profiler.TraceAnnotation")
+        elif mod == "jax.profiler":
+            heads.add(f"{alias}.TraceAnnotation")
+        elif mod == "repro.obs":
+            heads.add(f"{alias}.span")
+    for local, origin in mi.import_names.items():
+        if origin in (("jax.profiler", "TraceAnnotation"),
+                      ("repro.obs", "span")):
+            heads.add(local)
+        elif origin == ("jax", "profiler"):
+            heads.add(f"{local}.TraceAnnotation")
+        elif origin == ("repro", "obs"):
+            heads.add(f"{local}.span")
+    return heads
+
+
 def _numpy_aliases(mi) -> Set[str]:
     out = {alias for alias, mod in mi.import_modules.items()
            if mod in ("numpy", "np")}
@@ -84,6 +107,7 @@ def _check_traced_function(fi: FunctionInfo) -> List[Finding]:
     np_aliases = _numpy_aliases(mi)
     rnd_aliases = _stdlib_random_aliases(mi)
     jr_heads = _jax_random_heads(mi)
+    span_heads = _span_heads(mi)
     findings: List[Finding] = []
     # static argnames are concrete Python values at trace time — a
     # float()/np.asarray() on them is not a host sync
@@ -122,6 +146,13 @@ def _check_traced_function(fi: FunctionInfo) -> List[Finding]:
                 f"trace time; thread timestamps in as arguments"))
             continue
         if head is None:
+            continue
+        if head in span_heads:
+            findings.append(_finding(
+                fi, "jax-host-time", line,
+                f"'{head}(...)' in traced code — a host span opens once, "
+                f"at trace time, and times nothing that runs; open it "
+                f"around the call into the jitted function"))
             continue
         parts = head.split(".")
 

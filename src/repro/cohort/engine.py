@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.cohort.landmarks import LANDMARK_STRATEGIES, select_landmarks
 from repro.cohort.nystrom import nystrom_from_landmarks
 from repro.core import spectral as _spectral
@@ -265,9 +266,10 @@ class CohortEngine:
         dedupe on it: two tenants whose tables hash identically can ride
         one background solve (``repro.streaming.SolveDeduper``).
         """
-        h = hashlib.sha1(np.ascontiguousarray(embeds).tobytes())
-        h.update(str(embeds.shape).encode())
-        return h.digest()
+        with obs.span("engine.fingerprint"):
+            h = hashlib.sha1(np.ascontiguousarray(embeds).tobytes())
+            h.update(str(embeds.shape).encode())
+            return h.digest()
 
     _fingerprint = fingerprint                   # pre-streaming spelling
 
@@ -327,19 +329,22 @@ class CohortEngine:
         """
         embeds = np.ascontiguousarray(np.asarray(embeds, np.float32))
         st = self.state
-        fp = self.fingerprint(embeds)
         persist = key is None
-        if persist and st.fingerprint == fp and st.result is not None:
-            self.stats["cache_hits"] += 1
-            cached = st.result
-            return dataclasses.replace(
-                cached, source="cache", seconds=0.0,
-                # copies: the cached arrays back every future replay, a
-                # caller mutating its result must not corrupt them
-                assign=cached.assign.copy(),
-                embedding=cached.embedding.copy(),
-                evals=cached.evals.copy())
-        prep = self._prepare(embeds, fp, key=key, warm_ok=persist)
+        with self._prepare_span(embeds) as sp:
+            fp = self.fingerprint(embeds)
+            if persist and st.fingerprint == fp and st.result is not None:
+                sp.set_metadata(cached=1)
+                self.stats["cache_hits"] += 1
+                cached = st.result
+                return dataclasses.replace(
+                    cached, source="cache", seconds=0.0,
+                    # copies: the cached arrays back every future replay,
+                    # a caller mutating its result must not corrupt them
+                    assign=cached.assign.copy(),
+                    embedding=cached.embedding.copy(),
+                    evals=cached.evals.copy())
+            prep = self._prepare(embeds, fp, key=key, warm_ok=persist)
+            sp.set_metadata(warm=int(prep.warm))
         if persist:
             self.publish(prep)
         else:
@@ -359,10 +364,21 @@ class CohortEngine:
         it sees is the last published solve.
         """
         embeds = np.ascontiguousarray(np.asarray(embeds, np.float32))
-        fp = self.fingerprint(embeds)
-        if self.state.fingerprint == fp and self.state.result is not None:
-            return None
-        return self._prepare(embeds, fp, key=None, warm_ok=True)
+        with self._prepare_span(embeds) as sp:
+            fp = self.fingerprint(embeds)
+            if (self.state.fingerprint == fp
+                    and self.state.result is not None):
+                sp.set_metadata(cached=1)
+                return None
+            prep = self._prepare(embeds, fp, key=None, warm_ok=True)
+            sp.set_metadata(warm=int(prep.warm))
+            return prep
+
+    def _prepare_span(self, embeds: np.ndarray):
+        """The ``engine.prepare`` span: fingerprint to staged result."""
+        n = embeds.shape[0]
+        return obs.span("engine.prepare", method=self._resolve_method(n),
+                        n=n, cached=0)
 
     def publish(self, prep: PreparedSolve, *, count: bool = True,
                 ) -> CohortResult:
@@ -374,23 +390,24 @@ class CohortEngine:
         deduped solve computed by another tenant's engine is adopted, so
         "exactly one engine solve" stays true on dashboards.
         """
-        st = self.state
-        st.fingerprint, st.num_clients = prep.fingerprint, prep.num_clients
-        if not prep.warm:
-            st.sketch = prep.sketch          # new cold baseline
-        st.landmark_idx = prep.landmark_idx
-        st.gamma = prep.gamma
-        st.w_basis = prep.w_basis
-        st.mm_basis = prep.mm_basis
-        st.result = prep.result
-        if count:
-            self.stats["warm_starts" if prep.warm else "cold_starts"] += 1
-            self.stats["solves"] += 1
-            if prep.auto_m_evals is not None:
-                self._update_auto_m(prep.num_clients,
-                                    self.config.num_clusters,
-                                    prep.drift, prep.auto_m_evals)
-        return prep.result
+        with obs.span("engine.publish", warm=int(prep.warm)):
+            st = self.state
+            st.fingerprint, st.num_clients = prep.fingerprint, prep.num_clients
+            if not prep.warm:
+                st.sketch = prep.sketch          # new cold baseline
+            st.landmark_idx = prep.landmark_idx
+            st.gamma = prep.gamma
+            st.w_basis = prep.w_basis
+            st.mm_basis = prep.mm_basis
+            st.result = prep.result
+            if count:
+                self.stats["warm_starts" if prep.warm else "cold_starts"] += 1
+                self.stats["solves"] += 1
+                if prep.auto_m_evals is not None:
+                    self._update_auto_m(prep.num_clients,
+                                        self.config.num_clusters,
+                                        prep.drift, prep.auto_m_evals)
+            return prep.result
 
     def _prepare(self, embeds: np.ndarray, fp: bytes, *, key,
                  warm_ok: bool) -> PreparedSolve:
@@ -422,13 +439,15 @@ class CohortEngine:
         # baseline, so slow per-round drift ACCUMULATES and eventually
         # forces a cold refresh of landmarks + bandwidth (otherwise the
         # round-0 kernel would be reused forever under steady drift).
-        sketch = self._sketch(embeds)
-        drift = float("inf")
-        if st.sketch is not None and st.num_clients == n:
-            drift = float(np.linalg.norm(sketch - st.sketch)
-                          / (np.linalg.norm(st.sketch) + _SKETCH_EPS))
+        with obs.span("engine.sketch"):
+            sketch = self._sketch(embeds)
+            drift = float("inf")
+            if st.sketch is not None and st.num_clients == n:
+                drift = float(np.linalg.norm(sketch - st.sketch)
+                              / (np.linalg.norm(st.sketch) + _SKETCH_EPS))
 
-        x = jnp.asarray(embeds)
+        with obs.span("engine.upload"):
+            x = jnp.asarray(embeds)
         k = cfg.num_clusters
         # auto_k and landmark autotuning both need the lambda_k /
         # lambda_{k+1} gap, but the subspace solvers only return as many
@@ -436,39 +455,47 @@ class CohortEngine:
         # slice back after the gap is read off.
         widen = cfg.auto_k or (self._autotune_m and method != "dense")
         solve_k = k + 1 if widen else k
-        if method == "dense":
-            y, evals = self._solve_dense(x, solve_k)
-            warm = False
-            idx = gamma = w_basis = mm_basis = None
-        else:
-            y, evals, warm, idx, gamma, w_basis, mm_basis = \
-                self._solve_landmarks(x, solve_k, method, drift,
-                                      land_key, solve_key, warm_ok=warm_ok)
-        auto_m_evals = (np.asarray(evals)
-                        if self._autotune_m and method != "dense"
-                        and not warm else None)
+        with obs.span("engine.landmarks"):
+            if method == "dense":
+                y, evals = self._solve_dense(x, solve_k)
+                warm = False
+                idx = gamma = w_basis = mm_basis = None
+            else:
+                y, evals, warm, idx, gamma, w_basis, mm_basis = \
+                    self._solve_landmarks(x, solve_k, method, drift,
+                                          land_key, solve_key,
+                                          warm_ok=warm_ok)
+        auto_m_evals = None
+        if self._autotune_m and method != "dense" and not warm:
+            with obs.span("engine.wait", what="evals"):
+                auto_m_evals = np.asarray(evals)
 
         k_hat = k
         if cfg.auto_k:
-            k_hat = int(np.clip(
-                int(_spectral.eigengap_k(evals, k)), 2, k))
+            with obs.span("engine.wait", what="evals"):
+                k_hat = int(np.clip(
+                    int(_spectral.eigengap_k(evals, k)), 2, k))
             y = row_normalize(y[:, :k_hat])
         elif widen:
             y = row_normalize(y[:, :k])
-        assign, _ = kmeans(km_key, y, k_hat)
+        with obs.span("engine.kmeans"):
+            assign, _ = kmeans(km_key, y, k_hat)
 
+        with obs.span("engine.wait", what="result"):
+            assign = np.asarray(assign)
+            y, evals = np.asarray(y), np.asarray(evals)
         result = CohortResult(
-            assign=np.asarray(assign), k=k_hat,
-            embedding=np.asarray(y), evals=np.asarray(evals),
+            assign=assign, k=k_hat, embedding=y, evals=evals,
             method=method, source="warm" if warm else "cold", drift=drift,
             seconds=time.perf_counter() - t0)
-        return PreparedSolve(
-            fingerprint=fp, sketch=sketch, num_clients=n, result=result,
-            landmark_idx=None if idx is None else np.asarray(idx),
-            gamma=None if gamma is None else float(gamma),
-            w_basis=None if w_basis is None else np.asarray(w_basis),
-            mm_basis=None if mm_basis is None else np.asarray(mm_basis),
-            warm=warm, drift=drift, auto_m_evals=auto_m_evals)
+        with obs.span("engine.wait", what="state"):
+            return PreparedSolve(
+                fingerprint=fp, sketch=sketch, num_clients=n, result=result,
+                landmark_idx=None if idx is None else np.asarray(idx),
+                gamma=None if gamma is None else float(gamma),
+                w_basis=None if w_basis is None else np.asarray(w_basis),
+                mm_basis=None if mm_basis is None else np.asarray(mm_basis),
+                warm=warm, drift=drift, auto_m_evals=auto_m_evals)
 
     def select_batched(self, embeds, *, requests: int = 1) -> CohortResult:
         """One solve serving ``requests`` coalesced select calls.
@@ -579,8 +606,9 @@ class CohortEngine:
         else:
             idx = select_landmarks(land_key, x, m, cfg.landmarks)
             rows = x[:min(n, _spectral._GAMMA_SAMPLE_ROWS)]
-            gamma = float(_spectral.auto_gamma(
-                pairwise_sq_dists(rows, x[idx])))
+            gamma = _spectral.auto_gamma(pairwise_sq_dists(rows, x[idx]))
+            with obs.span("engine.wait", what="gamma"):
+                gamma = float(gamma)
         w_rank = (None if solver == "eigh"
                   else min(m, cfg.w_rank or max(8 * k, 64)))
         kwargs = dict(

@@ -56,6 +56,8 @@ from typing import Deque, List, Optional
 
 import numpy as np
 
+from repro import obs
+
 #: smoothing factor for the decode tokens/sec EMA in DecodeScheduler.stats().
 _TOK_S_EMA = 0.2
 
@@ -517,12 +519,15 @@ class CohortServer:
         self._delta_rows: List[np.ndarray] = []   # guarded-by: _write_lock
         self._delta_pending = 0           # guarded-by: _write_lock
         self._materializations = 0        # guarded-by: _write_lock
+        self._rows_materialized = 0       # guarded-by: _write_lock
 
         # streaming double-buffer: _published is the background solver's
         # finished (version, table, result); _served is the pair selects
         # currently draw from
         self._streaming = streaming
         self._published = None            # guarded-by: _publish_lock
+        # a select has swapped the mailbox's solve in
+        self._published_served = False    # guarded-by: _publish_lock
         self._served = None               # guarded-by: _select_lock
         self._closed = False              # guarded-by: _select_lock
         self._deduper = deduper
@@ -553,6 +558,8 @@ class CohortServer:
         # maps an observe_round outcome's client ids back to clusters
         self._last_assign = None                        # guarded-by: _select_lock
         self.prev_accuracy = 0.0                        # guarded-by: _select_lock
+        # sequence number of the latest select (spans' ``seq``)
+        self._select_seq = 0                            # guarded-by: _select_lock
         # parked (state_vec, actions, assign, table) until observe_round
         self._pending = None                            # guarded-by: _select_lock
         self._latency = {  # guarded-by: _stats_lock
@@ -564,9 +571,10 @@ class CohortServer:
             "rounds_observed": 0, "dropped_transitions": 0,
             # streaming: background warms landed / selects answered from
             # a warmed result / selects that had to solve inline / warms
-            # adopted from another tenant's identical-fingerprint solve
+            # adopted from another tenant's identical-fingerprint solve /
+            # warms replaced in the mailbox before any select served them
             "warm_ahead": 0, "served_warm": 0, "forced_inline": 0,
-            "dedupe_hit": 0}
+            "dedupe_hit": 0, "superseded": 0}
         self.last_select_s = 0.0                        # guarded-by: _select_lock
 
     # -- embedding table (versioned copy-on-write + delta buffer) --------
@@ -588,22 +596,30 @@ class CohortServer:
         return the same frozen array, and readers holding an older
         snapshot are never affected.
         """
-        return self._flush()
+        with obs.span("cohort.snapshot") as sp:
+            version, table, rows = self._flush()
+            sp.set_metadata(rows=rows)
+        return version, table
 
     def _flush(self):
-        """Apply pending deltas to the base table (self-locking)."""
+        """Apply pending deltas to the base table (self-locking).
+
+        Returns ``(version, table, rows materialized)``.
+        """
         with self._write_lock:
-            if self._delta_pending:
+            rows = self._delta_pending
+            if rows:
                 table = self._base.copy()
-                for ids, rows in zip(self._delta_ids, self._delta_rows):
-                    table[ids] = rows
+                for ids, delta in zip(self._delta_ids, self._delta_rows):
+                    table[ids] = delta
                 table.setflags(write=False)
                 self._base = table
                 self._delta_ids = []
                 self._delta_rows = []
                 self._delta_pending = 0
                 self._materializations += 1
-            return self._version, self._base
+                self._rows_materialized += rows
+            return self._version, self._base, rows
 
     def update_embeddings(self, client_ids, new_embeds) -> None:
         """Replace the embedding rows of ``client_ids``.
@@ -616,28 +632,34 @@ class CohortServer:
         enabled the update also marks this server dirty on the
         background solver, so a fresh solve starts warming immediately.
         """
-        ids = np.array(client_ids, dtype=np.int64)   # copy: deferred apply
-        rows = np.array(new_embeds, dtype=np.float32)
-        n, d = self._base.shape
-        if rows.ndim != 2 or rows.shape != (len(ids), d):
-            raise ValueError(f"rows shape {rows.shape} != ({len(ids)}, {d})")
-        if len(ids) and (ids.min() < -n or ids.max() >= n):
-            raise IndexError(f"client_ids out of range for {n} clients")
-        flush_now = False
-        with self._write_lock:
-            self._delta_ids.append(ids)
-            self._delta_rows.append(rows)
-            self._delta_pending += len(ids)
-            self._version += 1
-            # bound the buffer: once pending rows rival the table size a
-            # materialization is no longer a saving, only deferred work
-            flush_now = self._delta_pending >= n
-        if flush_now:
-            self._flush()
-        with self._stats_lock:
-            self._counters["updates"] += 1
-        if self._solver is not None:
-            self._solver.submit(id(self), self._background_warm)
+        with obs.span("cohort.update") as sp:
+            ids = np.array(client_ids, dtype=np.int64)   # copy: deferred
+            rows = np.array(new_embeds, dtype=np.float32)
+            n, d = self._base.shape
+            if rows.ndim != 2 or rows.shape != (len(ids), d):
+                raise ValueError(
+                    f"rows shape {rows.shape} != ({len(ids)}, {d})")
+            if len(ids) and (ids.min() < -n or ids.max() >= n):
+                raise IndexError(f"client_ids out of range for {n} clients")
+            flush_now = False
+            with self._write_lock:
+                self._delta_ids.append(ids)
+                self._delta_rows.append(rows)
+                self._delta_pending += len(ids)
+                self._version += 1
+                version = self._version
+                # bound the buffer: once pending rows rival the table
+                # size a materialization is no longer a saving, only
+                # deferred work
+                flush_now = self._delta_pending >= n
+            sp.set_metadata(version=version, rows=len(ids))
+            if flush_now:
+                with obs.span("cohort.flush"):
+                    self._flush()
+            with self._stats_lock:
+                self._counters["updates"] += 1
+            if self._solver is not None:
+                self._solver.submit(id(self), self._background_warm)
 
     # -- streaming (background warm + shutdown) ---------------------------
     def _background_warm(self) -> None:
@@ -650,46 +672,56 @@ class CohortServer:
         the next select to swap in.  Never takes ``_select_lock`` — the
         serving path is never blocked behind a background solve.
         """
-        version, table = self.snapshot()
-        with self._publish_lock:
-            pub = self._published
-        if pub is not None and pub[0] >= version:
-            return                      # already warmed this generation
-        ticket = prep = None
-        if self._deduper is not None:
-            from repro.cohort import CohortEngine
-            # key on (table content, engine config): identical tables
-            # under different cluster counts / methods must NOT share a
-            # solve — the adopted result's k would be wrong
-            ticket, prep = self._deduper.begin(
-                (CohortEngine.fingerprint(table), repr(self.config)))
-        if prep is not None:            # adopt another tenant's solve
-            with self._solve_lock:
-                res = self.engine.publish(prep, count=False)
-            with self._stats_lock:
-                self._counters["dedupe_hit"] += 1
-        else:
-            try:
+        with obs.span("cohort.warm", adopted=0) as sp:
+            version, table = self.snapshot()
+            sp.set_metadata(version=version)
+            with self._publish_lock:
+                pub = self._published
+            if pub is not None and pub[0] >= version:
+                return                  # already warmed this generation
+            ticket = prep = None
+            if self._deduper is not None:
+                from repro.cohort import CohortEngine
+                # key on (table content, engine config): identical tables
+                # under different cluster counts / methods must NOT share
+                # a solve — the adopted result's k would be wrong
+                ticket, prep = self._deduper.begin(
+                    (CohortEngine.fingerprint(table), repr(self.config)))
+            if prep is not None:        # adopt another tenant's solve
+                sp.set_metadata(adopted=1)
                 with self._solve_lock:
-                    own = self.engine.prepare(table)
-                    res = (None if own is None
-                           else self.engine.publish(own))
-            except BaseException:
+                    res = self.engine.publish(prep, count=False)
+                with self._stats_lock:
+                    self._counters["dedupe_hit"] += 1
+            else:
+                try:
+                    with self._solve_lock:
+                        own = self.engine.prepare(table)
+                        res = (None if own is None
+                               else self.engine.publish(own))
+                except BaseException:
+                    if ticket is not None:
+                        self._deduper.abort(ticket)
+                    raise
                 if ticket is not None:
-                    self._deduper.abort(ticket)
-                raise
-            if ticket is not None:
-                if own is not None:
-                    self._deduper.complete(ticket, own)
-                else:
-                    self._deduper.abort(ticket)
-            if res is None:
-                return                  # engine already current: no-op
-        with self._publish_lock:
-            if self._published is None or version > self._published[0]:
-                self._published = (version, table, res)
-        with self._stats_lock:
-            self._counters["warm_ahead"] += 1
+                    if own is not None:
+                        self._deduper.complete(ticket, own)
+                    else:
+                        self._deduper.abort(ticket)
+                if res is None:
+                    return              # engine already current: no-op
+            replaced = False
+            with obs.span("cohort.mailbox", version=version) as mb, \
+                    self._publish_lock:
+                if self._published is None or version > self._published[0]:
+                    replaced = (self._published is not None
+                                and not self._published_served)
+                    self._published = (version, table, res)
+                    self._published_served = False
+                mb.set_metadata(replaced=int(replaced))
+            with self._stats_lock:
+                self._counters["warm_ahead"] += 1
+                self._counters["superseded"] += int(replaced)
 
     def close(self, timeout: Optional[float] = None) -> None:
         """Stop serving: reject new selects, stop an owned solver.
@@ -705,14 +737,6 @@ class CohortServer:
             self._solver.close(timeout)
 
     # -- serving ----------------------------------------------------------
-    def _ema(self, name: str, value: float) -> None:
-        """Fold one latency sample into the EMA (takes the stats lock)."""
-        with self._stats_lock:
-            prev = self._latency[name]
-            self._latency[name] = (
-                value if self._counters["requests"] == 0
-                else prev + _LATENCY_EMA * (value - prev))
-
     def _policy_state(self, assign: np.ndarray,
                       table: np.ndarray) -> np.ndarray:
         from repro.fed.metrics import cluster_policy_state
@@ -777,7 +801,8 @@ class CohortServer:
                 "sizes_fn")
         if cohort_sizes is not None and not len(cohort_sizes):
             return []
-        with self._select_lock:
+        t_enter = time.perf_counter()
+        with obs.span("cohort.select") as sp, self._select_lock:
             if self._closed:
                 from repro.streaming import ServiceClosedError
                 raise ServiceClosedError("CohortServer is closed")
@@ -786,85 +811,104 @@ class CohortServer:
             if not sizes:
                 return []
             t0 = time.perf_counter()
+            self._select_seq += 1
             version, table = self.snapshot()
             res = None
+            served_warm = forced_inline = dropped = False
             if self._streaming is not None:
-                # drain the background solver's mailbox: swap in the
-                # warmed (version, table, result) if it is newer than
-                # what we're serving
-                with self._publish_lock:
-                    pub = self._published
-                if pub is not None and (self._served is None
-                                        or pub[0] > self._served[0]):
-                    self._served = pub
-                if self._served is not None:
-                    max_stale = self._streaming.max_stale_versions
-                    if (max_stale is None
-                            or version - self._served[0] <= max_stale):
-                        _, table, res = self._served
-                        with self._stats_lock:
-                            self._counters["served_warm"] += 1
+                with obs.span("cohort.swap") as sw:
+                    # drain the background solver's mailbox: swap in
+                    # the warmed (version, table, result) if it is
+                    # newer than what we're serving
+                    with self._publish_lock:
+                        pub = self._published
+                        if pub is not None and (
+                                self._served is None
+                                or pub[0] > self._served[0]):
+                            self._served = pub
+                            self._published_served = True
+                    if self._served is not None:
+                        sw.set_metadata(served=self._served[0])
+                        max_stale = self._streaming.max_stale_versions
+                        if (max_stale is None
+                                or version - self._served[0] <= max_stale):
+                            _, table, res = self._served
+                            served_warm = True
             if res is None:
                 # non-streaming, or nothing warmed yet / served version
                 # too stale: solve inline
-                with self._solve_lock:
+                with obs.span("cohort.inline_solve"), self._solve_lock:
                     res = self.engine.select_batched(
                         table, requests=len(sizes))
                 if self._streaming is not None:
                     self._served = (version, table, res)
-                    with self._stats_lock:
-                        self._counters["forced_inline"] += 1
+                    forced_inline = True
             t_solve = time.perf_counter()
             k = self.config.num_clusters
             self._last_assign = res.assign
-            pools = {c: list(np.flatnonzero(res.assign == c))
-                     for c in range(k)}
+            with obs.span("cohort.pools"):
+                pools = {c: list(np.flatnonzero(res.assign == c))
+                         for c in range(k)}
             cohorts: List[np.ndarray] = []
             if self.policy is not None:
-                state = self._policy_state(res.assign, table)
+                with obs.span("policy.state"):
+                    state = self._policy_state(res.assign, table)
                 all_actions: List[int] = []
-                for size in sizes:
-                    picked, actions = self.policy.draw(
-                        self.rng, state, pools, size)
-                    cohorts.append(np.asarray(picked[:size], np.int64))
-                    all_actions.extend(actions[: len(picked)])
-                if self._pending is not None:
-                    # the serve contract is select -> observe_round ->
-                    # select; a second select (or batch) before the
-                    # round report replaces the parked transition, and
-                    # the earlier draw is never learned from — count it
-                    # so the dashboard can see mis-sequenced callers
-                    with self._stats_lock:
-                        self._counters["dropped_transitions"] += 1
+                with obs.span("policy.draw"):
+                    for size in sizes:
+                        picked, actions = self.policy.draw(
+                            self.rng, state, pools, size)
+                        cohorts.append(np.asarray(picked[:size], np.int64))
+                        all_actions.extend(actions[: len(picked)])
+                # the serve contract is select -> observe_round ->
+                # select; a second select (or batch) before the round
+                # report replaces the parked transition, and the earlier
+                # draw is never learned from — count it so the
+                # dashboard can see mis-sequenced callers
+                dropped = self._pending is not None
                 self._pending = (state, all_actions, res.assign, table)
             else:
-                for pool in pools.values():
-                    self.rng.shuffle(pool)
-                for size in sizes:
-                    ordered = [pools[c] for c in range(res.k)]
-                    picked: List[int] = []
-                    while len(picked) < size and any(ordered):
-                        for pool in ordered:
-                            if pool and len(picked) < size:
-                                picked.append(pool.pop())
-                    cohorts.append(np.asarray(picked[:size], np.int64))
-            flat = (np.concatenate(cohorts) if cohorts
-                    else np.empty(0, np.int64))
-            if len(flat):
-                np.add.at(self._participation, res.assign[flat], 1.0)
-            # staleness: every cluster ages one select; those that just
-            # contributed a client reset to fresh
-            self._staleness += 1.0
-            if len(flat):
-                self._staleness[np.unique(res.assign[flat])] = 0.0
+                with obs.span("policy.draw"):
+                    for pool in pools.values():
+                        self.rng.shuffle(pool)
+                    for size in sizes:
+                        ordered = [pools[c] for c in range(res.k)]
+                        picked: List[int] = []
+                        while len(picked) < size and any(ordered):
+                            for pool in ordered:
+                                if pool and len(picked) < size:
+                                    picked.append(pool.pop())
+                        cohorts.append(np.asarray(picked[:size], np.int64))
+            with obs.span("cohort.account"):
+                flat = (np.concatenate(cohorts) if cohorts
+                        else np.empty(0, np.int64))
+                if len(flat):
+                    np.add.at(self._participation, res.assign[flat], 1.0)
+                # staleness: every cluster ages one select; those that just
+                # contributed a client reset to fresh
+                self._staleness += 1.0
+                if len(flat):
+                    self._staleness[np.unique(res.assign[flat])] = 0.0
             t1 = time.perf_counter()
-            self._ema("solve_s", t_solve - t0)
-            self._ema("draw_s", t1 - t_solve)
-            self._ema("total_s", t1 - t0)
             with self._stats_lock:
+                self._counters["served_warm"] += int(served_warm)
+                self._counters["forced_inline"] += int(forced_inline)
+                self._counters["dropped_transitions"] += int(dropped)
+                first = self._counters["requests"] == 0
+                for name, value in (("solve_s", t_solve - t0),
+                                    ("draw_s", t1 - t_solve),
+                                    ("total_s", t1 - t0)):
+                    prev = self._latency[name]
+                    self._latency[name] = (
+                        value if first
+                        else prev + _LATENCY_EMA * (value - prev))
                 self._counters["requests"] += len(sizes)
                 self._counters["batches"] += 1
             self.last_select_s = t1 - t0
+            served = version if self._served is None else self._served[0]
+            sp.set_metadata(seq=self._select_seq, requests=len(sizes),
+                            version=version, served=served,
+                            lock_wait_ns=int((t0 - t_enter) * 1e9))
             return [(picked, res) for picked in cohorts]
 
     def _outcome_cluster_rates(self, outcome):
@@ -931,7 +975,7 @@ class CohortServer:
         # same lock as select_cohort: a racing selection must not park a
         # new (state, actions) transition between our read of _pending
         # and its clear, or that round's learning step would be dropped
-        with self._select_lock:
+        with obs.span("cohort.observe") as sp, self._select_lock:
             if outcome is not None:
                 rates = self._outcome_cluster_rates(outcome)
                 if rates is not None:
@@ -940,15 +984,19 @@ class CohortServer:
                         avail[seen] - self._avail_ema[seen])
                     self._latency_ema_s[seen] += _REWARD_EMA * (
                         latency[seen] - self._latency_ema_s[seen])
+            sp.set_metadata(seq=self._select_seq)
             if self.policy is not None and self._pending is not None:
                 state, actions, assign, table = self._pending
                 for c in set(actions):
                     self._reward_ema[c] += _REWARD_EMA * (
                         reward - self._reward_ema[c])
                 self.prev_accuracy = accuracy
-                next_state = self._policy_state(assign, table)
-                self.policy.observe(state, actions, reward, next_state)
-                self.policy.train(self.rng)
+                with obs.span("policy.state"):
+                    next_state = self._policy_state(assign, table)
+                with obs.span("policy.observe"):
+                    self.policy.observe(state, actions, reward, next_state)
+                with obs.span("policy.train"):
+                    self.policy.train(self.rng)
                 self._pending = None
             else:
                 self.prev_accuracy = accuracy
@@ -980,12 +1028,14 @@ class CohortServer:
         "dqn").
 
         Streaming adds the flat ``warm_ahead`` / ``served_warm`` /
-        ``forced_inline`` / ``dedupe_hit`` counters (always present,
-        zero when disabled), ``shed`` (selects rejected by admission
-        control), and a ``streaming`` sub-dict: enabled flag,
-        ``max_stale_versions``, the version currently served vs the
-        table version, delta-buffer ``materializations``, and the
-        admission/solver breakdowns.
+        ``forced_inline`` / ``dedupe_hit`` / ``superseded`` counters
+        (always present, zero when disabled; ``superseded`` counts warm
+        solves replaced in the mailbox before any select served them),
+        ``shed`` (selects rejected by admission control), and a
+        ``streaming`` sub-dict: enabled flag, ``max_stale_versions``,
+        the version currently served vs the table version, delta-buffer
+        ``materializations`` and the ``rows_materialized`` they applied,
+        and the admission/solver breakdowns.
         """
         last = self.engine.state.result
         policy = {"kind": self.policy_name}
@@ -1009,6 +1059,7 @@ class CohortServer:
             "served_version": (None if self._served is None
                                else self._served[0]),
             "materializations": self._materializations,
+            "rows_materialized": self._rows_materialized,
             "admission": admission,
         }
         if self._own_solver and self._solver is not None:

@@ -37,6 +37,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import numpy as np
 
+from repro import obs
 from repro.core.dqn import DQNAgent, DQNConfig
 
 
@@ -134,7 +135,9 @@ class ClusterPolicy:
             Advances the agent's ε schedule by one step.
         """
         self.agent.steps += 1
-        q = self.agent.q_values(self._check_state(state_vec, "draw"))
+        # Q values to host: waits for a TD step still running on the device
+        with obs.span("policy.q"):
+            q = self.agent.q_values(self._check_state(state_vec, "draw"))
         eps = self.agent.epsilon()
         for pool in pools.values():
             rng.shuffle(pool)

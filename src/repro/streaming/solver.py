@@ -30,6 +30,8 @@ import time
 from collections import OrderedDict
 from typing import Callable, Optional
 
+from repro import obs
+
 __all__ = ["BackgroundSolver", "StreamingSpec"]
 
 _log = logging.getLogger(__name__)
@@ -98,8 +100,9 @@ class BackgroundSolver:
             raise ValueError(f"workers={workers} must be >= 1")
         self._queue_lock = threading.Lock()
         self._wake = threading.Event()
-        # key -> task; latest submit for a key replaces the pending one
-        self._dirty: "OrderedDict[object, Callable[[], None]]" = \
+        # key -> (task, first submit's monotonic ns, submits folded in);
+        # the latest submit for a key replaces the pending task
+        self._dirty: "OrderedDict[object, tuple]" = \
             OrderedDict()               # guarded-by: _queue_lock
         self._inflight: set = set()     # guarded-by: _queue_lock
         self._closed = False            # guarded-by: _queue_lock
@@ -124,21 +127,24 @@ class BackgroundSolver:
             self.stats["submitted"] += 1
             if key in self._dirty:
                 self.stats["coalesced"] += 1
-            self._dirty[key] = fn
+                _, first_ns, submits = self._dirty[key]
+                self._dirty[key] = (fn, first_ns, submits + 1)
+            else:
+                self._dirty[key] = (fn, time.monotonic_ns(), 1)
             self._dirty.move_to_end(key)
         self._wake.set()
         return True
 
     def _next_task(self):
         with self._queue_lock:
-            for key, fn in self._dirty.items():
+            for key, task in self._dirty.items():
                 # one in-flight task per key: the task snapshots the
                 # freshest table itself, so running two generations of
                 # the same tenant concurrently is pure waste
                 if key not in self._inflight:
                     del self._dirty[key]
                     self._inflight.add(key)
-                    return key, fn
+                    return key, task
             # nothing runnable (empty, or every dirty key already in
             # flight): clear under the lock — submit inserts under the
             # same lock before set(), and task completion re-sets the
@@ -152,14 +158,18 @@ class BackgroundSolver:
             with self._queue_lock:
                 if self._closed and not self._dirty:
                     return
-            key, fn = self._next_task()
-            if fn is None:
+            key, task = self._next_task()
+            if task is None:
                 self._wake.wait(timeout=0.05)
                 continue
+            fn, first_ns, submits = task
             try:
                 with self._queue_lock:
                     self.stats["runs"] += 1
-                fn()
+                with obs.span("solver.task",
+                              queued_ns=time.monotonic_ns() - first_ns,
+                              coalesced=submits - 1):
+                    fn()
             except Exception:
                 # a worker must outlive one bad task: log the traceback
                 # and count it, so callers can refuse to report success

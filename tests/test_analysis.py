@@ -68,6 +68,15 @@ def test_purity_good_is_clean():
     assert run_lint("purity_good.py") == []
 
 
+def test_purity_flags_host_spans_in_traced_code():
+    fs = run_lint("purity_span.py")
+    assert rules_of(fs) == {"jax-host-time"}
+    # the two spans in the jitted body and the one in its helper; the
+    # span around the call into it is host code and stays clean
+    assert sorted((f.symbol, f.line) for f in fs) == [
+        ("_helper", 19), ("spans_in_trace", 10), ("spans_in_trace", 12)]
+
+
 # -- pallas family ---------------------------------------------------------
 
 def test_pallas_bad_flags_all_three_rules():
