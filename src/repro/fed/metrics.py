@@ -31,7 +31,7 @@ are supported (the ``features`` knob, mirrored by
 
 from __future__ import annotations
 
-from typing import Optional
+from typing import Optional, Tuple
 
 import numpy as np
 
@@ -74,6 +74,13 @@ def _check_per_cluster(name: str, arr: np.ndarray, k: int) -> np.ndarray:
     return arr[:k]
 
 
+#: rows per partial sum in :func:`cluster_dispersion`: each cluster's sums
+#: are accumulated over blocks of this many rows, then the blocks added,
+#: which keeps a 10^6-row sum ~10^3 times closer to the exact one than
+#: one running sum over every row.
+_SUM_BLOCK = 1024
+
+
 def cluster_dispersion(embeds: np.ndarray, assign: np.ndarray,
                        k: int) -> np.ndarray:
     """Per-cluster embedding spread, scale-free and bounded to [0, 1).
@@ -83,23 +90,61 @@ def cluster_dispersion(embeds: np.ndarray, assign: np.ndarray,
     global centroid, squashed through ``x / (1 + x)``.  Empty clusters
     report 0.  A tight cluster sits near 0; one as diffuse as the whole
     table sits near 0.5; a cluster wider than the table tends to 1.
+
+    One float64 pass over the table: per-cluster counts, row sums and
+    squared-norm sums of the rows centred on the global mean (so a large
+    common offset does not cancel), each cluster's spread being its mean
+    squared norm less its centroid's squared norm.
     """
-    embeds = np.asarray(embeds, np.float64)
     assign = np.asarray(assign)
-    global_var = float(
-        np.mean(np.sum((embeds - embeds.mean(axis=0)) ** 2, axis=1)))
+    n = len(assign)
     out = np.zeros(k, np.float64)
+    if n == 0:
+        return out
+    # (d, n): each coordinate contiguous for bincount's weights
+    xt = np.asarray(np.asarray(embeds).T, np.float64, order="C")
+    xt -= xt.mean(axis=1, keepdims=True)
+    sq = np.einsum("ij,ij->j", xt, xt)
+    global_var = float(sq.mean())
     if global_var <= 0.0:
         return out
-    for c in range(k):
-        members = embeds[assign == c]
-        if len(members) == 0:
-            continue
-        var = float(np.mean(
-            np.sum((members - members.mean(axis=0)) ** 2, axis=1)))
-        ratio = var / global_var
-        out[c] = ratio / (1.0 + ratio)
+    # bin of each row: (row block, cluster); ids outside [0, k) go to a
+    # spare last bin, as they belong to no cluster
+    bins = -(-n // _SUM_BLOCK) * k
+    block = np.where((assign >= 0) & (assign < k),
+                     np.arange(n) // _SUM_BLOCK * k + assign, bins)
+
+    def per_cluster(weights=None):
+        return np.bincount(block, weights=weights, minlength=bins)[
+            :bins].reshape(-1, k).sum(axis=0)
+
+    count = per_cluster()
+    sums = np.stack([per_cluster(row) for row in xt], axis=1)
+    sq_sums = per_cluster(sq)
+    seen = count > 0
+    c = count[seen]
+    var = np.maximum(sq_sums[seen] / c - np.einsum(
+        "ij,ij->i", sums[seen], sums[seen]) / (c * c), 0.0)
+    ratio = var / global_var
+    out[seen] = ratio / (1.0 + ratio)
     return out
+
+
+def cluster_solve_stats(assign: np.ndarray, embeds: Optional[np.ndarray],
+                        k: int) -> Tuple[np.ndarray, Optional[np.ndarray]]:
+    """The per-solve half of :func:`cluster_policy_state`.
+
+    ``(population, dispersion)``: each cluster's share of the ``n``
+    clients, and :func:`cluster_dispersion` of ``embeds`` (``None`` when
+    ``embeds`` is ``None``, as the ``"basic"`` layout needs none).  Both
+    depend only on the solve's ``assign`` and the table it clustered, so
+    a server builds them once per solve it serves and passes them back
+    as ``cluster_policy_state(..., solve_stats=)``.
+    """
+    assign = np.asarray(assign)
+    pop = np.bincount(assign, minlength=k)[:k] / max(len(assign), 1)
+    disp = None if embeds is None else cluster_dispersion(embeds, assign, k)
+    return pop, disp
 
 
 def cluster_policy_state(assign: np.ndarray, k: int,
@@ -111,7 +156,8 @@ def cluster_policy_state(assign: np.ndarray, k: int,
                          staleness: Optional[np.ndarray] = None,
                          availability: Optional[np.ndarray] = None,
                          latency_s: Optional[np.ndarray] = None,
-                         features: str = "rich") -> np.ndarray:
+                         features: str = "rich",
+                         solve_stats: Optional[Tuple] = None) -> np.ndarray:
     """Serving-side DQN state: per-cluster stats + last global accuracy.
 
     Args:
@@ -123,7 +169,8 @@ def cluster_policy_state(assign: np.ndarray, k: int,
                        reward credited to draws from each cluster.
         prev_accuracy: global-model accuracy after the last round.
         embeds:        (n, d) embedding table behind ``assign``; required
-                       for ``features="rich"``/``"system"`` (dispersion).
+                       for ``features="rich"``/``"system"`` (dispersion)
+                       unless ``solve_stats`` is given.
         staleness:     (k,) count of selects since each cluster last
                        contributed a client to a served cohort; required
                        for ``features="rich"``/``"system"``.
@@ -134,6 +181,12 @@ def cluster_policy_state(assign: np.ndarray, k: int,
                        seconds; required for ``features="system"``.
         features:      ``"basic"`` (3k + 1) | ``"rich"`` (5k + 1) |
                        ``"system"`` (7k + 1).
+        solve_stats:   the per-solve half, ``cluster_solve_stats(assign,
+                       embeds, k)``, built earlier for this same solve;
+                       None builds it here.  ``CohortServer`` memoizes it
+                       per served solve (keyed on the ``assign`` and
+                       table objects), so a select or observe on a solve
+                       already described skips the O(n·d) pass.
 
     Returns:
         float32 vector ``[population_frac ‖ participation_frac ‖
@@ -147,25 +200,32 @@ def cluster_policy_state(assign: np.ndarray, k: int,
     if features not in STATE_FEATURES:
         raise ValueError(f"unknown state features {features!r}; "
                          f"expected one of {STATE_FEATURES}")
-    n = max(len(assign), 1)
-    pop = np.bincount(np.asarray(assign), minlength=k)[:k] / n
+    rich = features in ("rich", "system")
+    if solve_stats is None:
+        if rich and embeds is None:
+            raise ValueError(
+                f"cluster_policy_state: features={features!r} needs the "
+                "embedding table (embeds=) for the dispersion features; "
+                "pass features='basic' for the participation-only state")
+        solve_stats = cluster_solve_stats(assign, embeds if rich else None,
+                                          k)
+    pop, dispersion = solve_stats
     participation = _check_per_cluster("participation", participation, k)
     reward = _check_per_cluster("reward_ema", reward_ema, k)
     total = participation.sum()
     part = (participation / total) if total > 0 else np.full(k, 1.0 / k)
     parts = [pop, part, reward]
-    if features in ("rich", "system"):
-        if embeds is None:
+    if rich:
+        if dispersion is None:
             raise ValueError(
                 f"cluster_policy_state: features={features!r} needs the "
-                "embedding table (embeds=) for the dispersion features; "
-                "pass features='basic' for the participation-only state")
+                "dispersion in solve_stats (build it with embeds=)")
         if staleness is None:
             raise ValueError(
                 f"cluster_policy_state: features={features!r} needs the "
                 "per-cluster staleness counts (staleness=)")
         stale = _check_per_cluster("staleness", staleness, k)
-        parts.append(cluster_dispersion(embeds, assign, k))
+        parts.append(dispersion)
         parts.append(stale / (1.0 + stale))
     if features == "system":
         if availability is None or latency_s is None:

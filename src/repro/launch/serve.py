@@ -428,6 +428,15 @@ class CohortServer:
     solve (bounded staleness).  See docs/ARCHITECTURE.md ("Streaming
     re-clustering").
 
+    Serving state (``policy="dqn"``): the per-solve half of
+    ``cluster_policy_state`` (population share and dispersion, one
+    O(N·d) pass) is memoized for the solve it describes, keyed on that
+    solve's table and ``assign`` objects, both immutable.  A select that
+    swaps in or solves a new result rebuilds it; its ``observe_round``
+    and every later select on the same solve reuse it (``hit`` on the
+    ``policy.state`` span; ``state_stats_hits`` / ``state_stats_builds``
+    in :meth:`stats`).
+
     Args:
         num_clients:  N, rows of the embedding table.
         embed_dim:    d, embedding width.
@@ -562,6 +571,10 @@ class CohortServer:
         self._select_seq = 0                            # guarded-by: _select_lock
         # parked (state_vec, actions, assign, table) until observe_round
         self._pending = None                            # guarded-by: _select_lock
+        # (table, assign, cluster_solve_stats) of the solve the serving
+        # state last described: reused while selects and observes serve
+        # that same solve, rebuilt when another is served
+        self._state_memo = None                         # guarded-by: _select_lock
         self._latency = {  # guarded-by: _stats_lock
             "solve_s": 0.0, "draw_s": 0.0, "total_s": 0.0}
         # running means per RoundResult.timings phase
@@ -574,7 +587,10 @@ class CohortServer:
             # adopted from another tenant's identical-fingerprint solve /
             # warms replaced in the mailbox before any select served them
             "warm_ahead": 0, "served_warm": 0, "forced_inline": 0,
-            "dedupe_hit": 0, "superseded": 0}
+            "dedupe_hit": 0, "superseded": 0,
+            # DQN serving states whose per-solve half came from the memo
+            # (the served solve was the one last described) / was built
+            "state_stats_hits": 0, "state_stats_builds": 0}
         self.last_select_s = 0.0                        # guarded-by: _select_lock
 
     # -- embedding table (versioned copy-on-write + delta buffer) --------
@@ -737,19 +753,35 @@ class CohortServer:
             self._solver.close(timeout)
 
     # -- serving ----------------------------------------------------------
-    def _policy_state(self, assign: np.ndarray,
-                      table: np.ndarray) -> np.ndarray:
-        from repro.fed.metrics import cluster_policy_state
+    def _policy_state(self, assign: np.ndarray, table: np.ndarray):
+        """The DQN serving state for the solve ``(assign, table)``.
+
+        Returns ``(state, memo, hit)``.  The per-solve half of the state
+        (``cluster_solve_stats``: population share, dispersion) comes
+        from the memo when it describes this solve, i.e. holds these very
+        ``table`` and ``assign`` objects (both immutable), else is built
+        afresh; ``memo`` is the entry describing this solve, which the
+        caller, holding ``_select_lock``, stores back.  The O(k) half
+        (participation, reward EMA, staleness, availability, latency,
+        previous accuracy) is read anew on every call.
+        """
+        from repro.fed.metrics import cluster_policy_state, cluster_solve_stats
         rich = self.state_features in ("rich", "system")
         system = self.state_features == "system"
-        return cluster_policy_state(
-            assign, self.config.num_clusters,
+        k = self.config.num_clusters
+        memo = self._state_memo
+        hit = memo is not None and memo[0] is table and memo[1] is assign
+        if not hit:
+            memo = (table, assign,
+                    cluster_solve_stats(assign, table if rich else None, k))
+        state = cluster_policy_state(
+            assign, k,
             self._participation, self._reward_ema, self.prev_accuracy,
-            embeds=table if rich else None,
             staleness=self._staleness if rich else None,
             availability=self._avail_ema if system else None,
             latency_s=self._latency_ema_s if system else None,
-            features=self.state_features)
+            features=self.state_features, solve_stats=memo[2])
+        return state, memo, hit
 
     def select_cohort(self, cohort_size: int):
         """Serve one cohort; returns ``(client_ids, CohortResult)``.
@@ -815,6 +847,7 @@ class CohortServer:
             version, table = self.snapshot()
             res = None
             served_warm = forced_inline = dropped = False
+            state_hit = None
             if self._streaming is not None:
                 with obs.span("cohort.swap") as sw:
                     # drain the background solver's mailbox: swap in
@@ -851,8 +884,10 @@ class CohortServer:
                          for c in range(k)}
             cohorts: List[np.ndarray] = []
             if self.policy is not None:
-                with obs.span("policy.state"):
-                    state = self._policy_state(res.assign, table)
+                with obs.span("policy.state") as ps:
+                    state, self._state_memo, state_hit = self._policy_state(
+                        res.assign, table)
+                    ps.set_metadata(hit=int(state_hit))
                 all_actions: List[int] = []
                 with obs.span("policy.draw"):
                     for size in sizes:
@@ -894,6 +929,9 @@ class CohortServer:
                 self._counters["served_warm"] += int(served_warm)
                 self._counters["forced_inline"] += int(forced_inline)
                 self._counters["dropped_transitions"] += int(dropped)
+                if state_hit is not None:
+                    self._counters["state_stats_hits" if state_hit
+                                   else "state_stats_builds"] += 1
                 first = self._counters["requests"] == 0
                 for name, value in (("solve_s", t_solve - t0),
                                     ("draw_s", t1 - t_solve),
@@ -985,14 +1023,17 @@ class CohortServer:
                     self._latency_ema_s[seen] += _REWARD_EMA * (
                         latency[seen] - self._latency_ema_s[seen])
             sp.set_metadata(seq=self._select_seq)
+            state_hit = None
             if self.policy is not None and self._pending is not None:
                 state, actions, assign, table = self._pending
                 for c in set(actions):
                     self._reward_ema[c] += _REWARD_EMA * (
                         reward - self._reward_ema[c])
                 self.prev_accuracy = accuracy
-                with obs.span("policy.state"):
-                    next_state = self._policy_state(assign, table)
+                with obs.span("policy.state") as ps:
+                    next_state, self._state_memo, state_hit = \
+                        self._policy_state(assign, table)
+                    ps.set_metadata(hit=int(state_hit))
                 with obs.span("policy.observe"):
                     self.policy.observe(state, actions, reward, next_state)
                 with obs.span("policy.train"):
@@ -1008,6 +1049,9 @@ class CohortServer:
                         self._round_timings[phase] = (
                             prev + (seconds - prev) / (n + 1))
                 self._counters["rounds_observed"] += 1
+                if state_hit is not None:
+                    self._counters["state_stats_hits" if state_hit
+                                   else "state_stats_builds"] += 1
         return reward
 
     def stats(self) -> dict:
@@ -1026,6 +1070,10 @@ class CohortServer:
         ``last_select`` (method/source/drift/k of the latest solve), and
         ``policy`` (kind plus ε / state dim / steps / replay fill for
         "dqn").
+
+        ``state_stats_hits`` / ``state_stats_builds`` count the DQN
+        serving states (one per select, one per observe that credits a
+        draw) whose per-solve half was reused from the memo / built.
 
         Streaming adds the flat ``warm_ahead`` / ``served_warm`` /
         ``forced_inline`` / ``dedupe_hit`` / ``superseded`` counters

@@ -153,6 +153,22 @@ def test_tenants_are_isolated_seeds_policies_stats():
                for la, lb in zip(leaves_a, leaves_b))
 
 
+def test_each_tenant_keeps_its_own_state_memo():
+    """One memo per tenant server: a tenant's select builds its own
+    serving state's per-solve half, and another tenant's traffic never
+    replaces or counts against it."""
+    fe = mk_frontend(tenants=2, n=120, k=3, policy="dqn", seed=0)
+    a, b = fe.tenant_names
+    fe.select_cohort(a, 10)
+    fe.observe_round(a, 0.7)
+    fe.select_cohort(b, 10)
+    st = fe.stats()["tenants"]
+    assert (st[a]["state_stats_builds"], st[a]["state_stats_hits"]) == (1, 1)
+    assert (st[b]["state_stats_builds"], st[b]["state_stats_hits"]) == (1, 0)
+    memo_a, memo_b = fe.tenant(a)._state_memo, fe.tenant(b)._state_memo
+    assert memo_a[0] is not memo_b[0] and memo_a[1] is not memo_b[1]
+
+
 def test_duplicate_tenant_rejected():
     fe = CohortFrontend()
     fe.add_tenant("fam", TenantSpec("fam", 40, 4,
@@ -188,7 +204,7 @@ def test_rich_state_round_trip_through_observe_round():
     assert st["policy"]["state_features"] == "rich"
     # dispersion features live in [0, 1) and are not all zero for a
     # real blob table; staleness starts fresh after serving
-    state = srv._policy_state(res.assign, srv.embeds)
+    state = srv._policy_state(res.assign, srv.embeds)[0]
     disp = state[3 * k: 4 * k]
     stale = state[4 * k: 5 * k]
     assert np.all((disp >= 0) & (disp < 1)) and disp.max() > 0

@@ -12,6 +12,7 @@ exactly the names the program emits.
 import os
 import sys
 import time
+from types import SimpleNamespace as NS
 
 import jax
 import numpy as np
@@ -20,7 +21,7 @@ import pytest
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 from bench import spans as S  # noqa: E402
-from bench import trace  # noqa: E402
+from bench import spec, trace  # noqa: E402
 from repro import obs  # noqa: E402
 from repro.cohort import CohortConfig  # noqa: E402
 from repro.launch.serve import CohortServer  # noqa: E402
@@ -195,3 +196,21 @@ def test_counters_match_spans(traced):
     # the forced flush, then what each snapshot applied
     rows = sum(s.stats["rows"] for s in S.named(spans, "cohort.snapshot"))
     assert st["streaming"]["rows_materialized"] == N + rows
+
+
+def test_policy_state_spans_mark_memo_hits(traced):
+    """Each select serves a newly published solve and builds the state's
+    per-solve half (``hit`` = 0); its observe reuses it (``hit`` = 1).
+    The spans agree with the counters and with ``state_hit_share``."""
+    srv, spans, _, plain = traced
+    sel = [s.within("policy.state")[0].stats["hit"]
+           for s in S.named(spans, "cohort.select")]
+    obs_ = [o.within("policy.state")[0].stats["hit"]
+            for o in S.named(spans, "cohort.observe")]
+    assert sel == [0, 0, 0] and obs_ == [1, 1, 1]
+    st = srv.stats()
+    assert (st["state_stats_builds"], st["state_stats_hits"]) == (3, 3)
+    assert spec.reader("state_hit_share")(
+        NS(trace=NS(spans=spans))) == pytest.approx(50.0)
+    # a stratified server builds no state
+    assert not S.named(plain, "policy.state")

@@ -7,8 +7,11 @@ import numpy as np
 import pytest
 
 from repro.cohort import CohortConfig
+from repro.fed.metrics import (cluster_dispersion, cluster_policy_state,
+                               cluster_solve_stats)
 from repro.launch.serve import CohortServer
 from repro.policy import ClusterPolicy
+from repro.streaming import StreamingSpec
 
 FAST_DQN = {"hidden": (32,), "eps_decay_steps": 30, "buffer_size": 512,
             "batch_size": 64}
@@ -85,7 +88,7 @@ def test_cohort_server_dqn_shifts_draws_from_stale_cluster():
     assign = srv.engine.state.result.assign
     stale = int(np.argmax(np.bincount(assign[true == 0], minlength=k)))
     srv.policy.agent.steps = 10_000     # read weights at ε = eps_end
-    w = srv.policy.draw_weights(srv._policy_state(assign, srv.embeds))
+    w = srv.policy.draw_weights(srv._policy_state(assign, srv.embeds)[0])
     assert w[stale] < 1.0 / k
     assert int(np.argmax(w)) != stale
 
@@ -232,3 +235,177 @@ def test_cohort_server_stats_are_lock_protected_snapshots():
     # counters shared by the update path and the select path still agree
     server.update_embeddings(np.arange(4), np.zeros((4, 8), np.float32))
     assert server.stats()["updates"] == 2      # mk_server seeded 1 update
+
+
+# -- serving state: the per-solve half, built once per served solve -------
+
+def two_pass_dispersion(embeds, assign, k):
+    """Reference: a centred float64 pass for the global spread, then one
+    boolean-mask gather and a centred pass per cluster."""
+    embeds = np.asarray(embeds, np.float64)
+    assign = np.asarray(assign)
+    global_var = float(np.mean(np.sum(
+        (embeds - embeds.mean(axis=0)) ** 2, axis=1)))
+    out = np.zeros(k, np.float64)
+    if global_var <= 0.0:
+        return out
+    for c in range(k):
+        members = embeds[assign == c]
+        if len(members) == 0:
+            continue
+        var = float(np.mean(np.sum(
+            (members - members.mean(axis=0)) ** 2, axis=1)))
+        ratio = var / global_var
+        out[c] = ratio / (1.0 + ratio)
+    return out
+
+
+def dispersion_case(name):
+    """(embeds, assign, k): 3,000 rows, so several partial-sum blocks."""
+    x, true = blob_table(3000, 5, 8, seed=3)
+    if name == "blobs":
+        return x, true, 5
+    if name == "empty_clusters":               # clusters 5..7 hold no one
+        return x, true, 8
+    if name == "single_member_clusters":
+        assign = true.copy()
+        assign[[0, 1500, 2999]] = [5, 6, 7]
+        return x, assign, 8
+    if name == "constant_table":               # global spread 0
+        return np.full((500, 8), 2.5, np.float32), true[:500], 5
+    if name == "offset_1e4":                   # a large common offset
+        return x + np.float32(1e4), true, 5
+    if name == "ids_outside_0_k":              # belong to no cluster
+        assign = true.copy()
+        assign[::7], assign[3::11] = 9, -1
+        return x, assign, 5
+    raise ValueError(name)
+
+
+@pytest.mark.parametrize("case", [
+    "blobs", "empty_clusters", "single_member_clusters", "constant_table",
+    "offset_1e4", "ids_outside_0_k"])
+def test_one_pass_dispersion_matches_two_pass_reference(case):
+    """Same numbers as the per-cluster gathers, to summation order:
+    1e-12 relative (1e-15 absolute where the reference reads exactly 0,
+    as a one-member or empty cluster does)."""
+    x, assign, k = dispersion_case(case)
+    want = two_pass_dispersion(x, assign, k)
+    got = cluster_dispersion(x, assign, k)
+    np.testing.assert_allclose(got, want, rtol=1e-12, atol=1e-15)
+    if case == "constant_table":
+        assert not got.any()
+    if case == "empty_clusters":
+        assert not got[5:].any() and got[:5].all()
+
+
+def test_cluster_solve_stats_is_population_and_dispersion():
+    x, assign, k = dispersion_case("empty_clusters")
+    pop, disp = cluster_solve_stats(assign, x, k)
+    np.testing.assert_array_equal(pop, np.bincount(assign, minlength=k) / 3000)
+    np.testing.assert_array_equal(disp, cluster_dispersion(x, assign, k))
+    pop_basic, none = cluster_solve_stats(assign, None, k)
+    assert none is None
+    np.testing.assert_array_equal(pop_basic, pop)
+    # a rich state cannot be read from the basic layout's stats
+    zeros = np.zeros(k)
+    with pytest.raises(ValueError, match="dispersion in solve_stats"):
+        cluster_policy_state(assign, k, zeros, zeros, 0.5, staleness=zeros,
+                             solve_stats=(pop_basic, none))
+
+
+@pytest.mark.parametrize("features", ["basic", "rich", "system"])
+def test_state_memo_hit_is_bit_identical_to_a_fresh_build(features):
+    """After a select and its observe, the memo describes the served
+    solve; a state read through it equals cluster_policy_state built
+    from scratch on the same inputs, bit for bit."""
+    n, k, d = 120, 3, 8
+    x, _ = blob_table(n, k, d)
+    srv = CohortServer(n, d, seed=0, policy="dqn",
+                       config=CohortConfig(num_clusters=k),
+                       dqn_overrides=FAST_DQN, state_features=features)
+    srv.update_embeddings(np.arange(n), x)
+    _, res = srv.select_cohort(10)
+    srv.observe_round(0.7)
+    table = srv.embeds
+    state, memo, hit = srv._policy_state(res.assign, table)
+    assert hit and memo is srv._state_memo
+    rich, system = features != "basic", features == "system"
+    fresh = cluster_policy_state(
+        res.assign, k, srv._participation, srv._reward_ema,
+        srv.prev_accuracy, embeds=table if rich else None,
+        staleness=srv._staleness if rich else None,
+        availability=srv._avail_ema if system else None,
+        latency_s=srv._latency_ema_s if system else None,
+        features=features)
+    assert state.dtype == fresh.dtype == np.float32
+    np.testing.assert_array_equal(state, fresh)
+    assert (memo[2][1] is None) == (features == "basic")
+
+
+def test_state_memo_is_keyed_on_the_solve_objects():
+    """Inline solves hand each select a fresh assign (the engine's cache
+    copies it), so every select builds and its observe reuses; another
+    table or assign object, even equal in value, misses."""
+    srv, _ = mk_server()
+    for _ in range(2):
+        srv.select_cohort(10)
+        srv.observe_round(0.6)
+    st = srv.stats()
+    assert (st["state_stats_builds"], st["state_stats_hits"]) == (2, 2)
+    table, assign, _ = srv._state_memo
+    assert srv._policy_state(assign, table)[2]
+    assert not srv._policy_state(assign.copy(), table)[2]
+    assert not srv._policy_state(assign, table.copy())[2]
+    # a lookup alone stores nothing: only select and observe do
+    assert srv._state_memo[0] is table and srv._state_memo[1] is assign
+
+
+def test_state_memo_rebuilds_only_when_the_served_solve_changes():
+    """Streaming: a select that swaps in a new solve builds; its observe
+    and every select still serving that solve reuse the memo."""
+    n, k, d = 120, 3, 8
+    x, _ = blob_table(n, k, d)
+    srv = CohortServer(n, d, seed=0, policy="dqn",
+                       config=CohortConfig(num_clusters=k),
+                       dqn_overrides=FAST_DQN, streaming=StreamingSpec())
+
+    def publish(ids, rows):
+        warmed = srv.stats()["warm_ahead"]
+        srv.update_embeddings(ids, rows)
+        deadline = time.monotonic() + 60
+        while srv.stats()["warm_ahead"] <= warmed:
+            assert time.monotonic() < deadline, "no warm solve landed"
+            time.sleep(0.005)
+
+    def counts():
+        st = srv.stats()
+        return st["state_stats_builds"], st["state_stats_hits"]
+
+    try:
+        publish(np.arange(n), x)
+        srv.select_cohort(10)                       # new solve: build
+        assert counts() == (1, 0)
+        srv.observe_round(0.6)                      # same solve: hit
+        srv.select_cohort(10)                       # still served: hit
+        srv.observe_round(0.6)
+        assert counts() == (1, 3)
+        memo = srv._state_memo
+        publish(np.arange(8), x[:8] + 0.5)
+        srv.select_cohort(10)                       # swapped in: build
+        srv.observe_round(0.6)
+        assert counts() == (2, 4)
+        assert srv._state_memo[1] is not memo[1]
+        assert srv._state_memo[0] is not memo[0]
+        assert srv.stats()["served_warm"] == 3
+    finally:
+        srv.close(timeout=60)
+
+
+def test_stratified_server_builds_no_state():
+    srv, _ = mk_server(policy="stratified")
+    srv.select_cohort(8)
+    srv.observe_round(0.5)
+    st = srv.stats()
+    assert (st["state_stats_builds"], st["state_stats_hits"]) == (0, 0)
+    assert srv._state_memo is None
