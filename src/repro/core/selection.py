@@ -261,8 +261,8 @@ class DQREScSelection(SelectionPolicy):
         assign = self._cluster(state.client_embeds)
         s = self._state_vec(state, assign)
         self._last_assign, self._last_state_vec = assign, s
-        pools = {c: list(np.flatnonzero(assign == c))
-                 for c in range(self.num_clusters)}
+        from repro.policy import ClusterIndex
+        pools = ClusterIndex(assign, self.num_clusters).pools()
         picked, actions = self.cluster_policy.draw(
             self.rng, s, pools, self.clients_per_round)
         self._last_actions = actions

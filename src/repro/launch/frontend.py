@@ -22,9 +22,10 @@ shaped like the shared selector service of the FL-systems literature
   optional ``batch_window_s`` pre-wait), so requests queuing behind an
   earlier solve ride the next batch together.  One fingerprint-cache-
   consistent :class:`~repro.cohort.CohortResult` is fanned out to every
-  waiter, with the cluster pools partitioned across the batch so no
-  client is double-served within it.  A table-version bump opens a new
-  batch (requests against different versions never coalesce).
+  waiter, with the solve's cluster pools drawn without replacement
+  across the batch so no client is double-served within it.  A
+  table-version bump opens a new batch (requests against different
+  versions never coalesce).
 
 Synchronous callers lose nothing: with no concurrency a batch is just
 one request and the path degenerates to ``select_cohort``.
@@ -238,7 +239,7 @@ class CohortFrontend:
         coalesce: one caller (the leader) runs the engine once via
         ``CohortServer.select_cohorts`` and every waiter receives its
         own slice of the shared solve — cohorts within a batch are
-        disjoint because they pop the same cluster pools.
+        disjoint because they draw from one view of its cluster pools.
 
         A streaming tenant's admission control runs first: past the
         configured in-flight depth or token-bucket rate the request is

@@ -437,6 +437,15 @@ class CohortServer:
     ``policy.state`` span; ``state_stats_hits`` / ``state_stats_builds``
     in :meth:`stats`).
 
+    Cohort draws (both policies) go through a
+    :class:`repro.policy.ClusterIndex` of the served ``assign``, the
+    clients of each cluster grouped by one O(N) sort.  It is memoized
+    the same way, keyed on that ``assign`` object, so every select on
+    one served solve reuses it (``hit`` on the ``cohort.pools`` span;
+    ``pool_index_hits`` / ``pool_index_builds`` in :meth:`stats`); each
+    batch draws through a fresh :class:`repro.policy.ClusterPools` view
+    and leaves the index whole.
+
     Args:
         num_clients:  N, rows of the embedding table.
         embed_dim:    d, embedding width.
@@ -575,6 +584,8 @@ class CohortServer:
         # state last described: reused while selects and observes serve
         # that same solve, rebuilt when another is served
         self._state_memo = None                         # guarded-by: _select_lock
+        # (assign, ClusterIndex) of the solve last drawn from
+        self._index_memo = None                         # guarded-by: _select_lock
         self._latency = {  # guarded-by: _stats_lock
             "solve_s": 0.0, "draw_s": 0.0, "total_s": 0.0}
         # running means per RoundResult.timings phase
@@ -590,7 +601,9 @@ class CohortServer:
             "dedupe_hit": 0, "superseded": 0,
             # DQN serving states whose per-solve half came from the memo
             # (the served solve was the one last described) / was built
-            "state_stats_hits": 0, "state_stats_builds": 0}
+            "state_stats_hits": 0, "state_stats_builds": 0,
+            # selects whose cluster index came from the memo / was built
+            "pool_index_hits": 0, "pool_index_builds": 0}
         self.last_select_s = 0.0                        # guarded-by: _select_lock
 
     # -- embedding table (versioned copy-on-write + delta buffer) --------
@@ -783,6 +796,21 @@ class CohortServer:
             features=self.state_features, solve_stats=memo[2])
         return state, memo, hit
 
+    def _cluster_index(self, assign: np.ndarray):
+        """The :class:`repro.policy.ClusterIndex` of ``assign``.
+
+        Returns ``(memo, hit)``: ``memo`` is ``(assign, index)``, taken
+        from the memo when it holds this very ``assign`` object
+        (immutable), else built; the caller, holding ``_select_lock``,
+        stores it back.
+        """
+        from repro.policy import ClusterIndex
+        memo = self._index_memo
+        hit = memo is not None and memo[0] is assign
+        if not hit:
+            memo = (assign, ClusterIndex(assign, self.config.num_clusters))
+        return memo, hit
+
     def select_cohort(self, cohort_size: int):
         """Serve one cohort; returns ``(client_ids, CohortResult)``.
 
@@ -809,9 +837,10 @@ class CohortServer:
         :class:`repro.launch.frontend.CohortFrontend` batches concurrent
         ``select_cohort`` calls into: the embedding table is snapshotted
         once, the engine runs once (``select_batched``), and every
-        request draws from the **same shared cluster pools** — pools are
-        popped without replacement across the whole batch, so no client
-        is served to two cohorts of the same batch.  Returns one
+        request draws from the **same shared cluster pools** — one
+        :class:`repro.policy.ClusterPools` view of the solve's cluster
+        index, drawn without replacement across the whole batch, so no
+        client is served to two cohorts of the same batch.  Returns one
         ``(client_ids, CohortResult)`` pair per requested size; the
         ``CohortResult`` is the single solve shared by the batch.
 
@@ -877,11 +906,12 @@ class CohortServer:
                     self._served = (version, table, res)
                     forced_inline = True
             t_solve = time.perf_counter()
-            k = self.config.num_clusters
             self._last_assign = res.assign
-            with obs.span("cohort.pools"):
-                pools = {c: list(np.flatnonzero(res.assign == c))
-                         for c in range(k)}
+            with obs.span("cohort.pools") as cp:
+                self._index_memo, index_hit = self._cluster_index(
+                    res.assign)
+                cp.set_metadata(hit=int(index_hit))
+                pools = self._index_memo[1].pools()
             cohorts: List[np.ndarray] = []
             if self.policy is not None:
                 with obs.span("policy.state") as ps:
@@ -904,16 +934,17 @@ class CohortServer:
                 self._pending = (state, all_actions, res.assign, table)
             else:
                 with obs.span("policy.draw"):
-                    for pool in pools.values():
-                        self.rng.shuffle(pool)
+                    left = pools.left
                     for size in sizes:
-                        ordered = [pools[c] for c in range(res.k)]
-                        picked: List[int] = []
-                        while len(picked) < size and any(ordered):
-                            for pool in ordered:
-                                if pool and len(picked) < size:
-                                    picked.append(pool.pop())
-                        cohorts.append(np.asarray(picked[:size], np.int64))
+                        # round-robin over the clusters with members left
+                        slots: List[int] = []
+                        while len(slots) < size and any(left[:res.k]):
+                            for c in range(res.k):
+                                if left[c] and len(slots) < size:
+                                    left[c] -= 1
+                                    slots.append(c)
+                        cohorts.append(np.asarray(
+                            pools.take(self.rng, slots), np.int64))
             with obs.span("cohort.account"):
                 flat = (np.concatenate(cohorts) if cohorts
                         else np.empty(0, np.int64))
@@ -932,6 +963,8 @@ class CohortServer:
                 if state_hit is not None:
                     self._counters["state_stats_hits" if state_hit
                                    else "state_stats_builds"] += 1
+                self._counters["pool_index_hits" if index_hit
+                               else "pool_index_builds"] += 1
                 first = self._counters["requests"] == 0
                 for name, value in (("solve_s", t_solve - t0),
                                     ("draw_s", t1 - t_solve),
@@ -1073,7 +1106,9 @@ class CohortServer:
 
         ``state_stats_hits`` / ``state_stats_builds`` count the DQN
         serving states (one per select, one per observe that credits a
-        draw) whose per-solve half was reused from the memo / built.
+        draw) whose per-solve half was reused from the memo / built;
+        ``pool_index_hits`` / ``pool_index_builds`` count the selects
+        (either policy) whose cluster index was reused / built.
 
         Streaming adds the flat ``warm_ahead`` / ``served_warm`` /
         ``forced_inline`` / ``dedupe_hit`` / ``superseded`` counters

@@ -7,6 +7,7 @@ the simulation-only ``DQREScSelection`` so the serving path
 See docs/ARCHITECTURE.md ("The DQN policy loop") for the round-trip.
 """
 
-from repro.policy.cluster_policy import ClusterPolicy
+from repro.policy.cluster_policy import (ClusterIndex, ClusterPolicy,
+                                         ClusterPools)
 
-__all__ = ["ClusterPolicy"]
+__all__ = ["ClusterIndex", "ClusterPolicy", "ClusterPools"]

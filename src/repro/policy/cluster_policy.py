@@ -28,17 +28,101 @@ draws and the induced per-cluster draw weights are
 ``ε/k + (1-ε)·1[argmax Q]`` (see :meth:`ClusterPolicy.draw_weights`).
 The reward is the paper's accuracy-delta signal
 ``Ξ^(acc − target) − 1`` (FAVOR shaping, §3.3), computed by the caller.
+
+Cohort members come from a :class:`ClusterIndex` (the clients of each
+cluster, built once per assignment) through a per-batch
+:class:`ClusterPools` view: the slot loop counts what each cluster has
+left, and the members are then drawn by position, uniformly without
+replacement, so no pool of N client ids is ever listed or shuffled.
 """
 
 from __future__ import annotations
 
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
 
 from repro import obs
 from repro.core.dqn import DQNAgent, DQNConfig
+
+
+class ClusterIndex:
+    """The clients of each of ``num_clusters`` clusters, for one assignment.
+
+    One O(N) pass: a stable counting sort of ``assign`` groups the client
+    ids by cluster (``members(c)`` is a slice of that order) and
+    ``counts[c]`` is each cluster's size.  Immutable and never consumed:
+    every batch draws through its own :meth:`pools` view, so a cached
+    index serves any number of selects on the solve it describes.
+
+    Args:
+        assign:       (N,) cluster id of each client, in
+                      ``[0, num_clusters)``.
+        num_clusters: k, the number of clusters (and of actions).
+    """
+
+    def __init__(self, assign: np.ndarray, num_clusters: int):
+        assign = np.asarray(assign)
+        # a stable sort of a <= 16-bit key is a radix sort: O(N)
+        key = assign.astype(np.min_scalar_type(num_clusters))
+        self._order = np.argsort(key, kind="stable")
+        self.counts = np.bincount(assign, minlength=num_clusters)[
+            :num_clusters]
+        self._starts = np.concatenate([[0], np.cumsum(self.counts)[:-1]])
+        for a in (self._order, self.counts, self._starts):
+            a.setflags(write=False)
+
+    def members(self, c: int) -> np.ndarray:
+        """Client ids of cluster ``c``, ascending (a read-only view)."""
+        start = self._starts[c]
+        return self._order[start:start + self.counts[c]]
+
+    def pools(self) -> "ClusterPools":
+        """A fresh per-batch view holding every member of every cluster."""
+        return ClusterPools(self)
+
+
+class ClusterPools:
+    """One batch's draw from a :class:`ClusterIndex`.
+
+    ``left[c]`` is what cluster ``c`` still has for this batch: a slot
+    loop counts its choices off it, then :meth:`take` hands out members
+    for those slots.  Members taken by one request of the batch are out
+    of reach of the next, so the batch's cohorts are disjoint; the index
+    itself is left as it was.
+    """
+
+    def __init__(self, index: ClusterIndex):
+        self.index = index
+        self.left = index.counts.tolist()
+        # positions (within members(c)) this batch has taken, ascending
+        self._taken = [np.empty(0, np.int64)] * len(self.left)
+
+    def take(self, rng: np.random.Generator,
+             actions: Sequence[int]) -> List[int]:
+        """Client ids for slots drawn from clusters ``actions``.
+
+        The slots must already be counted off :attr:`left`.  Each
+        cluster's share is drawn in one ``rng.choice`` (Floyd's method,
+        O(slots)) among the positions this batch has not yet taken, so
+        members come uniformly without replacement, and are handed to
+        that cluster's slots in order.
+        """
+        actions = np.asarray(actions, np.int64)
+        picked = np.empty(len(actions), np.int64)
+        for c in np.unique(actions):
+            slots = np.flatnonzero(actions == c)
+            taken = self._taken[c]
+            free = int(self.index.counts[c]) - len(taken)
+            ranks = rng.choice(free, len(slots), replace=False)
+            # the r-th free position is r plus the taken ones at or
+            # below it: taken[i] - i free positions precede taken[i]
+            pos = ranks + np.searchsorted(
+                taken - np.arange(len(taken)), ranks, side="right")
+            self._taken[c] = np.sort(np.concatenate([taken, pos]))
+            picked[slots] = self.index.members(c)[pos]
+        return picked.tolist()
 
 
 class ClusterPolicy:
@@ -115,18 +199,18 @@ class ClusterPolicy:
         return w
 
     def draw(self, rng: np.random.Generator, state_vec: np.ndarray,
-             pools: Dict[int, List[int]], cohort_size: int,
+             pools: ClusterPools, cohort_size: int,
              ) -> Tuple[List[int], List[int]]:
         """Draw a cohort: one ε-greedy cluster choice per slot.
 
         Args:
-            rng:       caller's generator (shuffles pools + exploration).
+            rng:       caller's generator (exploration + member draws).
             state_vec: (state_dim,) state the Q function scores.
-            pools:     cluster id -> mutable list of member client ids;
-                       drawn clients are popped (no replacement).  Keys
-                       must cover ``range(num_clusters)``; empty lists
-                       mark clusters with no members (e.g. above the
-                       engine's eigengap k̂).
+            pools:     the batch's :class:`ClusterPools` over
+                       ``num_clusters`` clusters; the drawn clients are
+                       taken from it (no replacement across the batch).
+                       A cluster with nothing left (e.g. above the
+                       engine's eigengap k̂) is never chosen.
             cohort_size: number of clients to draw.
 
         Returns:
@@ -139,25 +223,23 @@ class ClusterPolicy:
         with obs.span("policy.q"):
             q = self.agent.q_values(self._check_state(state_vec, "draw"))
         eps = self.agent.epsilon()
-        for pool in pools.values():
-            rng.shuffle(pool)
+        left = pools.left
         order = np.argsort(-q)
-        picked: List[int] = []
         actions: List[int] = []
-        while len(picked) < cohort_size:
+        while len(actions) < cohort_size:
             if rng.random() < eps:
                 c = int(rng.integers(self.num_clusters))
             else:
-                c = int(next((c for c in order if pools[c]), order[0]))
-            if not pools[c]:
+                c = int(next((c for c in order if left[c]), order[0]))
+            if not left[c]:
                 nonempty = [cc for cc in range(self.num_clusters)
-                            if pools[cc]]
+                            if left[cc]]
                 if not nonempty:
                     break
                 c = int(rng.choice(nonempty))
-            picked.append(pools[c].pop())
+            left[c] -= 1
             actions.append(c)
-        return picked, actions
+        return pools.take(rng, actions), actions
 
     # -- learning ---------------------------------------------------------
     def observe(self, state_vec: np.ndarray, actions: Sequence[int],

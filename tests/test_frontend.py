@@ -39,7 +39,7 @@ def mk_frontend(tenants=2, n=120, k=3, d=8, policy="stratified", seed=0,
 def test_concurrent_selects_coalesce_to_one_solve_disjoint_cohorts():
     """16 concurrent selects on one table version: exactly one engine
     solve for that version, every request served, and the batch's
-    cohorts pairwise disjoint (shared pools, popped without
+    cohorts pairwise disjoint (shared pools, drawn without
     replacement)."""
     n, workers = 200, 16
     fe = mk_frontend(tenants=1, n=n, k=4, window=0.5)

@@ -214,3 +214,36 @@ def test_policy_state_spans_mark_memo_hits(traced):
         NS(trace=NS(spans=spans))) == pytest.approx(50.0)
     # a stratified server builds no state
     assert not S.named(plain, "policy.state")
+
+
+def test_pool_spans_mark_index_hits(traced, tmp_path):
+    """Each select of the traced server serves a newly published solve
+    and builds its cluster index (``hit`` = 0 on ``cohort.pools``), as
+    does the plain server's inline solve; selects that serve one solve
+    again reuse it (``hit`` = 1).  The spans agree with the counters."""
+    srv, spans, _, plain = traced
+    assert [s.within("cohort.pools")[0].stats["hit"]
+            for s in S.named(spans, "cohort.select")] == [0, 0, 0]
+    st = srv.stats()
+    assert (st["pool_index_builds"], st["pool_index_hits"]) == (3, 0)
+    (inline,) = S.named(plain, "cohort.select")
+    assert inline.within("cohort.pools")[0].stats["hit"] == 0
+
+    rng = np.random.default_rng(1)
+    quiet = CohortServer(256, D, seed=2, policy="stratified",
+                         config=CohortConfig(num_clusters=K),
+                         streaming=StreamingSpec())
+
+    def body():
+        quiet.update_embeddings(
+            np.arange(256), rng.normal(size=(256, D)).astype(np.float32))
+        _wait(lambda: quiet.stats()["warm_ahead"] >= 1)
+        for _ in range(3):
+            quiet.select_cohort(16)
+        quiet.close(timeout=120)
+
+    hits = [s.within("cohort.pools")[0].stats["hit"]
+            for s in S.named(_trace(str(tmp_path), body), "cohort.select")]
+    assert hits == [0, 1, 1]
+    st = quiet.stats()
+    assert (st["pool_index_builds"], st["pool_index_hits"]) == (1, 2)

@@ -2,6 +2,7 @@
 
 import threading
 import time
+from types import SimpleNamespace
 
 import numpy as np
 import pytest
@@ -10,7 +11,7 @@ from repro.cohort import CohortConfig
 from repro.fed.metrics import (cluster_dispersion, cluster_policy_state,
                                cluster_solve_stats)
 from repro.launch.serve import CohortServer
-from repro.policy import ClusterPolicy
+from repro.policy import ClusterIndex, ClusterPolicy
 from repro.streaming import StreamingSpec
 
 FAST_DQN = {"hidden": (32,), "eps_decay_steps": 30, "buffer_size": 512,
@@ -60,16 +61,121 @@ def test_cluster_policy_draw_contract():
     k = 4
     pol = ClusterPolicy(k, state_dim=3, seed=0, dqn_overrides=FAST_DQN)
     rng = np.random.default_rng(0)
-    pools = {0: list(range(0, 5)), 1: list(range(5, 10)),
-             2: [], 3: list(range(10, 12))}
+    # cluster 0: clients 0-4, 1: 5-9, 2: none, 3: 10-11
+    pools = ClusterIndex(np.repeat([0, 1, 3], [5, 5, 2]), k).pools()
     picked, actions = pol.draw(rng, np.zeros(3, np.float32), pools, 8)
     assert len(picked) == 8 == len(actions)
     assert len(set(picked)) == 8
     assert 2 not in actions             # empty cluster never credited
     # pool exhaustion: asking for more than exists returns what's there
-    pools = {c: ([0, 1] if c == 0 else []) for c in range(k)}
+    pools = ClusterIndex(np.array([0, 0]), k).pools()
     picked, actions = pol.draw(rng, np.zeros(3, np.float32), pools, 8)
     assert sorted(picked) == [0, 1]
+
+
+@pytest.mark.parametrize("earlier", [0, 3], ids=["fresh", "after_take"])
+def test_pool_draws_are_uniform_within_a_cluster(earlier):
+    """Many draws of 3 from a 10-member cluster, first in a batch or
+    after an earlier take from the same cluster, give every member a
+    frequency within 5% of uniform (4.6 sd at 20,000 batches): a draw
+    that kept to a fixed prefix of the index would give 3 members all
+    of it."""
+    members, draws, batches = 10, 3, 20_000
+    index = ClusterIndex(np.zeros(members, np.int64), 1)
+    rng = np.random.default_rng(7)
+    seen = np.zeros(members, np.int64)
+    for _ in range(batches):
+        pools = index.pools()
+        before = pools.take(rng, [0] * earlier)
+        picked = pools.take(rng, [0] * draws)
+        assert len(set(before + picked)) == earlier + draws
+        seen[picked] += 1
+    expected = batches * draws / members
+    assert np.all(np.abs(seen - expected) < 0.05 * expected), seen
+
+
+POLICIES = CohortServer.POLICIES
+
+
+def planted_server(policy, n=120, k=4):
+    """A server whose engine hands back one fixed solve: cluster c holds
+    the clients i with i % k == c, but cluster 2 is empty (its clients
+    are in cluster 3).  The same ``assign`` object on every select."""
+    assign = np.arange(n) % k
+    assign[assign == 2] = 3
+    assign.setflags(write=False)
+    res = SimpleNamespace(assign=assign, k=k)
+    srv = CohortServer(n, 8, seed=0, policy=policy,
+                       config=CohortConfig(num_clusters=k),
+                       dqn_overrides=FAST_DQN if policy == "dqn" else None)
+    srv.engine.select_batched = lambda table, requests: res
+    return srv, assign
+
+
+@pytest.mark.parametrize("policy", POLICIES)
+def test_batch_cohorts_are_disjoint_and_full(policy):
+    srv, _ = planted_server(policy)
+    cohorts = [ids for ids, _ in srv.select_cohorts([20, 20, 20])]
+    assert [len(ids) for ids in cohorts] == [20, 20, 20]
+    assert len(np.unique(np.concatenate(cohorts))) == 60
+
+
+@pytest.mark.parametrize("policy", POLICIES)
+def test_exhausted_pools_return_what_exists(policy):
+    """The batch asks for more than the 120 clients: the first cohort is
+    full, the second gets the 20 left, and together they are all."""
+    srv, _ = planted_server(policy)
+    (a, _), (b, _) = srv.select_cohorts([100, 100])
+    assert (len(a), len(b)) == (100, 20)
+    assert np.array_equal(np.sort(np.concatenate([a, b])), np.arange(120))
+    (c, _), = srv.select_cohorts([130])
+    assert np.array_equal(np.sort(c), np.arange(120))
+
+
+@pytest.mark.parametrize("policy", POLICIES)
+def test_empty_cluster_is_never_drawn(policy):
+    srv, assign = planted_server(policy)
+    ids, _ = srv.select_cohort(30)
+    assert len(ids) == 30 and 2 not in assign[ids]
+    if policy == "dqn":
+        actions = srv._pending[1]
+        assert len(actions) == 30 and 2 not in actions
+        assert np.array_equal(assign[ids], actions)
+    else:                               # round-robin over 0, 1 and 3
+        assert np.bincount(assign[ids], minlength=4).tolist() == [
+            10, 10, 0, 10]
+
+
+@pytest.mark.parametrize("policy", POLICIES)
+def test_selects_on_one_solve_draw_from_the_whole_partition(policy):
+    """The cached index is never consumed: a second select on the same
+    solve again gets a full 100 of the 120 clients."""
+    srv, _ = planted_server(policy)
+    for _ in range(2):
+        ids, _ = srv.select_cohort(100)
+        assert len(ids) == 100 == len(np.unique(ids))
+        if policy == "dqn":
+            srv.observe_round(0.6)
+    st = srv.stats()
+    assert (st["pool_index_builds"], st["pool_index_hits"]) == (1, 1)
+
+
+@pytest.mark.parametrize("policy", POLICIES)
+def test_cached_index_is_unchanged_by_draws(policy):
+    srv, assign = planted_server(policy)
+    srv.select_cohort(10)
+    memo_assign, index = srv._index_memo
+    assert memo_assign is assign
+    before = [index.members(c).copy() for c in range(4)]
+    counts = index.counts.copy()
+    srv.select_cohorts([50, 50, 50])
+    assert srv._index_memo[1] is index
+    np.testing.assert_array_equal(index.counts, counts)
+    for c in range(4):
+        np.testing.assert_array_equal(index.members(c), before[c])
+        np.testing.assert_array_equal(index.members(c),
+                                      np.flatnonzero(assign == c))
+    assert not index.members(0).flags.writeable
 
 
 # -- CohortServer: DQN-policy serving ------------------------------------
@@ -409,3 +515,42 @@ def test_stratified_server_builds_no_state():
     st = srv.stats()
     assert (st["state_stats_builds"], st["state_stats_hits"]) == (0, 0)
     assert srv._state_memo is None
+
+
+@pytest.mark.parametrize("policy", POLICIES)
+def test_pool_index_builds_once_per_served_solve(policy):
+    """Streaming: a static table is one build, however many selects
+    serve it; an update whose solve is swapped in is a second."""
+    n, k, d = 120, 3, 8
+    x, _ = blob_table(n, k, d)
+    srv = CohortServer(n, d, seed=0, policy=policy,
+                       config=CohortConfig(num_clusters=k),
+                       dqn_overrides=FAST_DQN if policy == "dqn" else None,
+                       streaming=StreamingSpec())
+
+    def publish(ids, rows):
+        warmed = srv.stats()["warm_ahead"]
+        srv.update_embeddings(ids, rows)
+        deadline = time.monotonic() + 60
+        while srv.stats()["warm_ahead"] <= warmed:
+            assert time.monotonic() < deadline, "no warm solve landed"
+            time.sleep(0.005)
+
+    def counts():
+        st = srv.stats()
+        return st["pool_index_builds"], st["pool_index_hits"]
+
+    try:
+        publish(np.arange(n), x)
+        for _ in range(3):
+            ids, _ = srv.select_cohort(10)
+            assert len(ids) == 10
+            srv.observe_round(0.6)
+        assert counts() == (1, 2)
+        index = srv._index_memo[1]
+        publish(np.arange(8), x[:8] + 0.5)
+        srv.select_cohort(10)
+        assert counts() == (2, 2)
+        assert srv._index_memo[1] is not index
+    finally:
+        srv.close(timeout=60)
